@@ -34,6 +34,9 @@ def main() -> None:
         bench_stream,
     )
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     os.makedirs("results/bench", exist_ok=True)
     suites = [
         ("table1_sharing", bench_sharing.run),

@@ -3,10 +3,10 @@
 #
 #   scripts/check.sh          # fast lane, then the slow remainder = full tier-1
 #   scripts/check.sh --fast   # fast lane only (-m "not slow", target < 5 min)
-#   scripts/check.sh --accel  # ONLY the compiled-Pallas lane (-m accel):
-#                             # REPRO_PALLAS_INTERPRET=0 parity on real
-#                             # TPU/GPU hardware (tests skip on CPU) —
-#                             # DESIGN.md §13
+#
+# The compiled-Pallas lane has no flag here: tests/test_tpu_compile.py
+# compiles the kernels for a described v5e chip inside tier-1, and on a TPU
+# host `python chip_smoke.py` renders through them (DESIGN.md §13).
 #
 # The fast lane is the quick signal: golden-image checksums (both backends),
 # every non-slow parity/unit suite, with per-test timings reported so creep
@@ -16,14 +16,6 @@
 # anything twice.
 set -euo pipefail
 cd "$(dirname "$0")/.."
-
-if [[ "${1:-}" == "--accel" ]]; then
-    echo "== compiled-Pallas lane (-m accel, REPRO_PALLAS_INTERPRET=0) =="
-    REPRO_PALLAS_INTERPRET=0 python -m pytest -x -q -m "accel" \
-        --durations=15 -rs
-    echo "check.sh --accel: OK"
-    exit 0
-fi
 
 echo "== tier-1 tests: fast lane (-m 'not slow') =="
 python -m pytest -x -q -m "not slow" --durations=15

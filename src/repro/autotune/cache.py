@@ -33,6 +33,8 @@ import tempfile
 import threading
 from typing import Dict, Optional
 
+import jax
+
 from repro.core.pipeline import RenderConfig, register_render_cache
 from repro.obs import get_registry
 from repro.sharding.scene import ShardedScene
@@ -58,18 +60,22 @@ def cache_path() -> str:
 
 def autotune_signature(scene, width: int, height: int, cfg: RenderConfig,
                        mesh=None) -> tuple:
-    """The cache key: (scene geometry, resolution, backend, mesh layout).
+    """The cache key: (scene geometry, resolution, backend, mesh layout,
+    device).
 
     Scene geometry is the gaussian count (+ shard layout for a pre-sharded
     scene); the config contributes every knob that changes which candidate
     wins EXCEPT the three swept ones (tile/group/tile_capacity — the result,
     not the key — plus group_capacity, which the search derives from them).
+    The device's platform and kind close the key: a winner timed on the CPU
+    never steers a TPU run through the persisted file.
     """
     if isinstance(scene, ShardedScene):
         geom = ("sharded", scene.num_shards, scene.shard_size)
     else:
         geom = ("scene", int(scene.num_gaussians))
     mesh_shape = tuple(sorted(dict(mesh.shape).items())) if mesh is not None else ()
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
     return (
         geom,
         int(width), int(height),
@@ -78,6 +84,7 @@ def autotune_signature(scene, width: int, height: int, cfg: RenderConfig,
         cfg.span, cfg.chunk, cfg.early_exit,
         cfg.scene_shards, cfg.feature_gather,
         mesh_shape,
+        device.platform, device.device_kind,
     )
 
 

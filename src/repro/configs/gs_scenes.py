@@ -48,3 +48,39 @@ EVAL_RESOLUTION: Dict[str, tuple] = {
     "rubble": (640, 480),
     "residence": (640, 448),
 }
+
+
+@dataclasses.dataclass(frozen=True)
+class ServeSpec:
+    """How a paper scene is served at paper scale (``paper_gaussians``,
+    native resolution, tile 16 / group 64): an orbit of cameras at
+    ``orbit_radius`` scene extents, and the static table sizes that keep
+    every such frame lossless (``overflow == span_overflow == 0``).
+
+    The sizes were read off the synthetic stand-in: the candidate window
+    needed by the largest screen footprint, and the longest group and tile
+    entry lists, measured at a fraction of the Gaussians and scaled up, with
+    headroom. ``chip_smoke.py`` asserts the lossless condition on the chip.
+    """
+
+    gaussians: int
+    orbit_radius: float
+    span: int
+    group_capacity: int
+    tile_capacity: int
+
+
+SERVE_SPECS: Dict[str, ServeSpec] = {
+    # Paper size. 1/16 of the Gaussians at 3 extents: span 4, longest group
+    # list 14.6k and longest tile list 3.9k once scaled by 16.
+    "train": ServeSpec(1_026_000, 3.0, span=6, group_capacity=24576,
+                       tile_capacity=8192),
+    # A quarter of the paper size, at native resolution. At 9.06M Gaussians
+    # the longest group list (~49k) pads every one of the 3888 groups'
+    # kernel feature blocks to 14 GB, past one chip's 16 GB, and the
+    # replicated one-chip render it is checked against cannot run. 1/64 of
+    # the Gaussians at 4 extents: span 4, longest lists 49k (group) and
+    # 13.7k (tile) once scaled by 64.
+    "rubble": ServeSpec(2_265_000, 4.0, span=6, group_capacity=16384,
+                        tile_capacity=6144),
+}

@@ -11,6 +11,8 @@ from typing import Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils import HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class Camera:
@@ -100,4 +102,4 @@ def orbit_cameras(
 
 def world_to_cam(R: jnp.ndarray, t: jnp.ndarray, xyz: jnp.ndarray) -> jnp.ndarray:
     """(N,3) world points -> camera frame."""
-    return xyz @ R.T + t[None, :]
+    return jnp.matmul(xyz, R.T, precision=HIGHEST) + t[None, :]

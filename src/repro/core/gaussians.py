@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.utils import HIGHEST
+
 SH_C0 = 0.28209479177387814  # Y_0^0
 
 
@@ -75,7 +77,7 @@ def covariance3d(log_scales: jnp.ndarray, quats: jnp.ndarray) -> jnp.ndarray:
     R = quat_to_rotmat(quats)
     S = jnp.exp(log_scales)
     M = R * S[..., None, :]  # R @ diag(S)
-    return M @ jnp.swapaxes(M, -1, -2)
+    return jnp.matmul(M, jnp.swapaxes(M, -1, -2), precision=HIGHEST)
 
 
 def random_scene(

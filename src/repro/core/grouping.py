@@ -29,7 +29,13 @@ from repro.utils import cdiv, wide_count_dtype, wide_count_sum
 
 @dataclasses.dataclass(frozen=True)
 class GridSpec:
-    """Static geometry of the tile/group decomposition."""
+    """Static geometry of the tile/group decomposition.
+
+    The image need not be a whole number of tiles: the grid covers it with
+    ``cdiv`` tiles/groups per axis and the last row/column of tiles runs past
+    the image edge (native resolutions such as 1959x1090). Every stage crops
+    those pixels; boundary tests use the full tile rect, which only widens
+    the conservative candidate set."""
 
     width: int
     height: int
@@ -40,8 +46,8 @@ class GridSpec:
     def __post_init__(self):
         if self.group % self.tile != 0:
             raise ValueError("group size must be a multiple of tile size")
-        if self.width % self.tile or self.height % self.tile:
-            raise ValueError("image dims must be multiples of the tile size")
+        if self.width <= 0 or self.height <= 0:
+            raise ValueError("image dims must be positive")
 
     @property
     def gf(self) -> int:
@@ -148,19 +154,21 @@ def identify(
     by0 = jnp.clip(jnp.floor((my - r) / bin_px).astype(jnp.int32), 0, n_bins_y - 1)
     by1 = jnp.clip(jnp.floor((my + r) / bin_px).astype(jnp.int32), 0, n_bins_y - 1)
 
-    dx = jnp.arange(span, dtype=jnp.int32)
-    dy = jnp.arange(span, dtype=jnp.int32)
-    # (N, span) each
-    cand_x = bx0[:, None] + dx[None, :]
-    cand_y = by0[:, None] + dy[None, :]
-    in_bbox_x = cand_x <= bx1[:, None]
-    in_bbox_y = cand_y <= by1[:, None]
+    # Candidate window laid out (span_x, span_y, N): the Gaussian axis is
+    # the minor (lane) axis, so a TPU tiles these arrays densely; an
+    # (N, span, span) layout would pad span to 128 lanes. Fields of shape
+    # (N,) and (N, k)[..., i] broadcast against it directly.
+    off = jnp.arange(span, dtype=jnp.int32)[:, None]
+    cand_x = bx0[None, :] + off            # (span, N)
+    cand_y = by0[None, :] + off
+    in_bbox_x = cand_x <= bx1[None, :]
+    in_bbox_y = cand_y <= by1[None, :]
 
-    # (N, span, span)
-    cx = cand_x[:, :, None]
-    cy = cand_y[:, None, :]
-    in_bbox = in_bbox_x[:, :, None] & in_bbox_y[:, None, :]
-    in_bbox = in_bbox & proj.valid[:, None, None]
+    # (span_x, span_y, N)
+    cx = cand_x[:, None, :]
+    cy = cand_y[None, :, :]
+    in_bbox = in_bbox_x[:, None, :] & in_bbox_y[None, :, :]
+    in_bbox = in_bbox & proj.valid[None, None, :]
 
     rect = (
         (cx * bin_px).astype(jnp.float32),
@@ -168,17 +176,21 @@ def identify(
         ((cx + 1) * bin_px).astype(jnp.float32),
         ((cy + 1) * bin_px).astype(jnp.float32),
     )
-    # Broadcast Projected fields to (N, 1, 1) for the test.
-    bproj = _BroadcastProj(proj)
-    hit = in_bbox & boundary_test(method, bproj, rect)
+    hit = in_bbox & boundary_test(method, proj, rect)
 
     bin_id = jnp.where(hit, cy * n_bins_x + cx, num_bins).astype(jnp.int32)
+    depth = jnp.where(hit, proj.depth[None, None, :], jnp.inf)
     N, S = proj.mean2d.shape[0], span
-    flat = lambda a: a.reshape(N * S * S)
     gauss_idx = jnp.broadcast_to(
-        jnp.arange(N, dtype=jnp.int32)[:, None, None], (N, S, S)
+        jnp.arange(N, dtype=jnp.int32)[None, None, :], (S, S, N)
     )
-    depth = jnp.where(hit, proj.depth[:, None, None], jnp.inf)
+    # Pair order is Gaussian-major, then window x, then window y: the
+    # insertion order the stable binning sort breaks depth ties by. The
+    # barrier keeps XLA from hoisting the transpose into the window
+    # computation, which would bring the padded layout back.
+    bin_id, gauss_idx, depth, hit_b = jax.lax.optimization_barrier(
+        (bin_id, gauss_idx, depth, hit))
+    flat = lambda a: jnp.transpose(a, (2, 0, 1)).reshape(N * S * S)
 
     # Span-window overflow: bbox bins beyond the static window.
     full_w = jnp.where(proj.valid, bx1 - bx0 + 1, 0)
@@ -189,24 +201,11 @@ def identify(
         bin_id=flat(bin_id),
         gauss_idx=flat(gauss_idx),
         depth=flat(depth).astype(jnp.float32),
-        valid=flat(hit),
+        valid=flat(hit_b),
         n_candidate_tests=wide_count_sum(in_bbox),
         n_pairs=jnp.sum(hit.astype(jnp.int32)),
         n_span_overflow=jnp.sum(lost),
     )
-
-
-class _BroadcastProj:
-    """View of Projected with (N,) fields lifted to (N, 1, 1)."""
-
-    def __init__(self, proj: Projected):
-        self._p = proj
-
-    def __getattr__(self, name):
-        v = getattr(self._p, name)
-        if v.ndim == 1:
-            return v[:, None, None]
-        return v[:, None, None, :]
 
 
 def bin_pairs(pairs: PairSet, num_bins: int, capacity: int) -> BinTable:

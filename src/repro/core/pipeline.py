@@ -100,7 +100,7 @@ class RenderConfig:
     group_capacity: int = 512          # K: entries per group segment
     tile_capacity: int = 256           # K_t: entries per tile segment
     span: int = 4                      # candidate window at group level (bins)
-    chunk: int = 32                    # raster gaussian chunk
+    chunk: int = 32                    # reference raster gaussian chunk
     early_exit: bool = True
     backend: str = "reference"         # stage implementation: reference | pallas
     scene_shards: int = 1              # D: gaussian-axis shards (DESIGN.md §10);
@@ -412,7 +412,7 @@ def _run_backend(
         #    affect masks: masks are per-entry — which is also why bitmasks
         #    need no cross-shard pass: they run on the already-merged table).
         masks = backend.bitmasks(
-            proj, table, grid, cfg.boundary_tile, chunk=cfg.chunk
+            proj, table, grid, cfg.boundary_tile
         )
         # 5) RM FIFO: per-tile compaction by bitmask (linear, order-
         #    preserving). Materialized by the reference backend; virtual
@@ -551,7 +551,7 @@ def frontend_stats(
             grid.num_groups, cfg.group_capacity, gather,
         )
         masks = backend.bitmasks(
-            proj, gtable, grid, cfg.boundary_tile, chunk=cfg.chunk
+            proj, gtable, grid, cfg.boundary_tile
         )
         compacted = backend.compact(gtable, masks, grid, cfg.tile_capacity)
         pixels_per_bin = cfg.tile * cfg.tile

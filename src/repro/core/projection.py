@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.core.camera import Camera
 from repro.core.gaussians import GaussianScene, covariance3d, SH_C0
+from repro.utils import HIGHEST
 
 # Low-pass filter added to the 2D covariance diagonal (anti-aliasing), exactly
 # as in the reference 3D-GS rasterizer.
@@ -207,7 +208,7 @@ def project(scene: GaussianScene, cam: Camera) -> Projected:
     """The preprocessing stage: features + culling (paper Fig 1)."""
     R = jnp.asarray(cam.R)
     t = jnp.asarray(cam.t)
-    p_cam = scene.means3d @ R.T + t[None, :]  # (N, 3)
+    p_cam = jnp.matmul(scene.means3d, R.T, precision=HIGHEST) + t[None, :]
     x, y, z = p_cam[:, 0], p_cam[:, 1], p_cam[:, 2]
     z_safe = jnp.maximum(z, 1e-6)
 
@@ -217,7 +218,8 @@ def project(scene: GaussianScene, cam: Camera) -> Projected:
 
     # --- 2D covariance via the projective Jacobian (EWA splatting) ---
     cov3d = covariance3d(scene.log_scales, scene.quats)      # (N, 3, 3)
-    cov3d_cam = jnp.einsum("ij,njk,lk->nil", R, cov3d, R)     # R Σ R^T
+    cov3d_cam = jnp.einsum("ij,njk,lk->nil", R, cov3d, R,
+                           precision=HIGHEST)                  # R Σ R^T
     inv_z = 1.0 / z_safe
     inv_z2 = inv_z * inv_z
     # J = [[fx/z, 0, -fx x / z^2], [0, fy/z, -fy y / z^2]]
@@ -233,7 +235,10 @@ def project(scene: GaussianScene, cam: Camera) -> Projected:
         ],
         axis=-2,
     )  # (N, 2, 3)
-    cov2d_full = J @ cov3d_cam @ jnp.swapaxes(J, -1, -2)      # (N, 2, 2)
+    cov2d_full = jnp.matmul(
+        jnp.matmul(J, cov3d_cam, precision=HIGHEST),
+        jnp.swapaxes(J, -1, -2), precision=HIGHEST,
+    )                                                          # (N, 2, 2)
     a = cov2d_full[:, 0, 0] + COV2D_BLUR
     b = cov2d_full[:, 0, 1]
     c = cov2d_full[:, 1, 1] + COV2D_BLUR
@@ -265,7 +270,7 @@ def project(scene: GaussianScene, cam: Camera) -> Projected:
     )
 
     # --- color + opacity ---
-    cam_pos = -R.T @ t
+    cam_pos = -jnp.matmul(R.T, t, precision=HIGHEST)
     dirs = scene.means3d - cam_pos[None, :]
     dirs = dirs / (jnp.linalg.norm(dirs, axis=-1, keepdims=True) + 1e-12)
     rgb = eval_sh(scene.sh, dirs)

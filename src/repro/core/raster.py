@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.core.grouping import BinTable, GridSpec
 from repro.core.projection import Projected, QMAX_3SIGMA, proj_take
+from repro.utils import HIGHEST
 
 ALPHA_MIN = 1.0 / 255.0
 ALPHA_MAX = 0.99
@@ -121,7 +122,7 @@ def rasterize(
                 w = jnp.where(live, w, 0.0)
             else:
                 live = jnp.ones_like(w, dtype=jnp.bool_)
-            c_run = c_run + w @ cl
+            c_run = c_run + jnp.matmul(w, cl, precision=HIGHEST)
             t_run = t_run * cp[:, -1]
             a_ops = a_ops + jnp.sum(
                 live.astype(jnp.int32) * (op > 0).astype(jnp.int32)[None, :]

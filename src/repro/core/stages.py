@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import abc
 import dataclasses
-import math
 import threading
 from typing import Dict, Optional
 
@@ -136,12 +135,8 @@ class Backend(abc.ABC):
         gtable: BinTable,
         grid: GridSpec,
         method: str,
-        *,
-        chunk: int = 32,
     ) -> GroupBitmasks:
-        """``chunk`` is the raster chunk size — a layout hint so kernel
-        backends can pack features once with the padding rasterization will
-        want (the gathers then CSE under jit). Pure-XLA backends ignore it."""
+        """Per-entry tile bitmasks over the group table."""
 
     # -- stage 5: RM FIFO compaction -------------------------------------
     @abc.abstractmethod
@@ -190,7 +185,7 @@ class ReferenceBackend(Backend):
 
     name = "reference"
 
-    def bitmasks(self, proj, gtable, grid, method, *, chunk=32):
+    def bitmasks(self, proj, gtable, grid, method):
         return generate_bitmasks(proj, gtable, grid, method)
 
     def compact(self, gtable, masks, grid, tile_capacity):
@@ -225,7 +220,9 @@ class PallasBackend(Backend):
     The fused RM never materializes per-tile tables; tile_capacity is honored
     in-register (entries past the capacity of a member tile's virtual FIFO are
     dropped, exactly like the reference compaction clamp), and alpha/blend op
-    counters are accumulated inside the kernel.
+    counters are accumulated inside the kernel. The kernels stream entries in
+    their own 128-lane chunks (kernels/layout.LANE); the raster ``chunk``
+    argument is the reference rasterizer's and is not read here.
     """
 
     name = "pallas"
@@ -237,38 +234,17 @@ class PallasBackend(Backend):
     def interpret(self) -> Optional[bool]:
         return self._interpret
 
-    def _resolve_interpret(self) -> bool:
-        from repro.kernels.ops import default_interpret
-
-        return default_interpret() if self._interpret is None else self._interpret
-
-    @staticmethod
-    def _pad_multiple(chunk: int) -> int:
-        from repro.kernels.layout import LANE
-
-        return math.lcm(LANE, max(int(chunk), 1))
-
-    def bitmasks(self, proj, gtable, grid, method, *, chunk=32):
+    def bitmasks(self, proj, gtable, grid, method):
         from repro.kernels.bitmask_gen import bitmask_kernel
         from repro.kernels.layout import pack_features
-        from repro.kernels.ops import group_origins, tiles_in_image
 
         # Same padding rasterize_groups uses => the gather is an identical
         # expression there and XLA CSE merges the two under jit (the hot
         # paths — render_jit/render_batch — are jit'd; eager render() pays
         # the gather twice, acceptable for demos/tests).
-        feat = pack_features(
-            proj, gtable.gauss_idx, gtable.entry_valid,
-            multiple=self._pad_multiple(chunk),
-        )
+        feat = pack_features(proj, gtable.gauss_idx, gtable.entry_valid)
         masks = bitmask_kernel(
-            feat,
-            group_origins(grid),
-            tiles_in_image(grid),
-            grid.tile,
-            grid.gf,
-            method=method,
-            interpret=self._resolve_interpret(),
+            feat, grid, method=method, interpret=self._interpret
         )
         # Kernel masks cover the padded K axis; crop to the table capacity.
         masks = masks[:, : gtable.capacity]
@@ -285,22 +261,17 @@ class PallasBackend(Backend):
 
     def rasterize_tiles(self, proj, table, grid, *, background, chunk, early_exit):
         from repro.kernels.layout import pack_features
-        from repro.kernels.ops import assemble_image_tiles, tile_origins
+        from repro.kernels.ops import assemble_image_tiles
         from repro.kernels.raster_tile import raster_tile_kernel
 
-        feat = pack_features(
-            proj, table.gauss_idx, table.entry_valid,
-            multiple=self._pad_multiple(chunk),
-        )
-        K = feat.shape[-1]
+        feat = pack_features(proj, table.gauss_idx, table.entry_valid)
         out, counts = raster_tile_kernel(
             feat,
-            tile_origins(grid),
+            grid.n_tiles_x,
             grid.tile,
-            chunk=min(chunk, K),
             early_exit=early_exit,
             with_stats=True,
-            interpret=self._resolve_interpret(),
+            interpret=self._interpret,
         )
         return TileRaster(
             image=assemble_image_tiles(out, grid, background),
@@ -313,13 +284,10 @@ class PallasBackend(Backend):
         background, chunk, early_exit, tile_capacity,
     ):
         from repro.kernels.layout import pack_features
-        from repro.kernels.ops import assemble_image, group_origins
+        from repro.kernels.ops import assemble_image
         from repro.kernels.raster_tile import raster_group_fused_kernel
 
-        feat = pack_features(
-            proj, gtable.gauss_idx, gtable.entry_valid,
-            multiple=self._pad_multiple(chunk),
-        )
+        feat = pack_features(proj, gtable.gauss_idx, gtable.entry_valid)
         K = feat.shape[-1]
         pad = K - masks.masks.shape[1]
         padded_masks = (
@@ -328,14 +296,13 @@ class PallasBackend(Backend):
         out, counts = raster_group_fused_kernel(
             feat,
             padded_masks,
-            group_origins(grid),
+            grid.n_groups_x,
             grid.tile,
             grid.gf,
-            chunk=min(chunk, K),
             early_exit=early_exit,
             tile_capacity=tile_capacity,
             with_stats=True,
-            interpret=self._resolve_interpret(),
+            interpret=self._interpret,
         )
         return TileRaster(
             image=assemble_image(out, grid, background),
@@ -527,13 +494,13 @@ class TimedBackend(Backend):
 
         return self._run("bin", ("bin_s", num_bins, capacity), build, pairs_s)
 
-    def bitmasks(self, proj, gtable, grid, method, *, chunk=32):
+    def bitmasks(self, proj, gtable, grid, method):
         inner = self.inner
 
         def build():
-            return lambda p, g: inner.bitmasks(p, g, grid, method, chunk=chunk)
+            return lambda p, g: inner.bitmasks(p, g, grid, method)
 
-        return self._run("bitmask", ("bitmask", grid, method, chunk),
+        return self._run("bitmask", ("bitmask", grid, method),
                          build, proj, gtable)
 
     def compact(self, gtable, masks, grid, tile_capacity):

@@ -42,7 +42,9 @@ multi-host serving remains the next commit-time decision to land.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import itertools
 import sys
 import threading
@@ -141,6 +143,21 @@ def _timed_batch(one):
         return jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
 
     return fn
+
+
+def _vmap_cameras(one, mesh):
+    """``one`` (scene, R, t, fx, fy, cx, cy, background) vmapped over a
+    camera batch whose axis lies over ``mesh``'s data axes. Naming those
+    axes lets a compiled Pallas kernel (kernels/ops.py::on_every_device) run
+    on each device's own lanes instead of on the whole batch everywhere."""
+    spmd = camera_batch_pspec(mesh)[0] if data_extent(mesh) > 1 else None
+    return jax.vmap(one, in_axes=(None, 0, 0, 0, 0, 0, 0, None),
+                    spmd_axis_name=spmd)
+
+
+def _with_mesh(mesh, fn, *args):
+    with jax.set_mesh(mesh):
+        return fn(*args)
 
 
 class Renderer:
@@ -601,10 +618,13 @@ class Renderer:
                 fn = (
                     jax.jit(one)
                     if kind == "single"
-                    else jax.jit(
-                        jax.vmap(one, in_axes=(None, 0, 0, 0, 0, 0, 0, None))
-                    )
+                    else jax.jit(_vmap_cameras(one, self._mesh))
                 )
+        if self._mesh.size > 1:
+            # Trace and run under the handle's mesh: compiled Pallas kernels
+            # find it there and run per device (kernels/ops.py::
+            # on_every_device), since XLA cannot partition them.
+            fn = functools.partial(_with_mesh, self._mesh, fn)
         while len(self._fns) >= _FN_CACHE_MAX:
             self._fns.pop(next(iter(self._fns)))
         self._fns[key] = fn
@@ -661,6 +681,29 @@ class Renderer:
             jnp.float32(cam.cx), jnp.float32(cam.cy),
             _background_array(background),
         )
+
+    def memory_analysis(self, cam: Camera):
+        """The compiler's per-device memory plan (argument, output and temp
+        bytes) of the single-camera program at ``cam``'s geometry. After a
+        ``render(cam)`` this reads the program that ran; nothing compiles
+        twice."""
+        self._check_open()
+        self._resolve_tile_params(cam)
+        fn = self._fn("single", cam)
+        under_mesh = isinstance(fn, functools.partial)
+        if under_mesh:
+            fn = fn.args[1]                       # unwrap _with_mesh
+        if not hasattr(fn, "lower"):
+            raise ValueError("timed-stage handles run eagerly: no program")
+        with (jax.set_mesh(self._mesh) if under_mesh
+              else contextlib.nullcontext()):
+            return fn.lower(
+                self._scene,
+                jnp.asarray(cam.R), jnp.asarray(cam.t),
+                jnp.float32(cam.fx), jnp.float32(cam.fy),
+                jnp.float32(cam.cx), jnp.float32(cam.cy),
+                _background_array(None),
+            ).compile().memory_analysis()
 
     def render_frontend(self, cam: Camera) -> FrontendResult:
         """Run ONLY the frontend half (project -> identify -> bin -> merge)

@@ -74,6 +74,10 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.core.camera import Camera
     from repro.core.gaussians import scene_like_paper
     from repro.core.pipeline import RenderConfig

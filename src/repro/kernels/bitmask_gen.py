@@ -2,8 +2,8 @@
 
 Per group entry, runs the chosen boundary test against each of the gf^2
 member tiles and packs the results into a uint32 bitmask. The ASIC's four
-tile-check units become VPU lanes: each BK-wide entry chunk tests all member
-tiles with the tile loop unrolled at trace time (static gf^2 <= 16).
+tile-check units become VPU lanes: each lane block of entries tests all
+member tiles with the tile loop unrolled at trace time (static gf^2 <= 16).
 """
 from __future__ import annotations
 
@@ -23,8 +23,10 @@ from repro.kernels.layout import (
     F_MEAN_Y,
     F_RADIUS,
     F_VALID,
+    LANE,
     NUM_FEATURES,
 )
+from repro.kernels.ops import on_every_device, resolve_interpret
 
 QMAX = 9.0
 SIGMA_CUT = 3.0
@@ -73,58 +75,65 @@ def _ellipse(mx, my, A, B, C, x0, y0, x1, y1):
 
 def bitmask_kernel(
     feat: jnp.ndarray,          # (num_groups, F, K)
-    group_origin: jnp.ndarray,  # (num_groups, 2) float32
-    tile_in_image: jnp.ndarray, # (num_groups, tpg) bool -> float32 mask
-    tile_px: int,
-    gf: int,
+    grid,                       # GridSpec: group origins + in-image tiles
     method: str = "ellipse",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Returns (num_groups, K) uint32 bitmasks."""
-    num_groups, F, K = feat.shape
-    assert F == NUM_FEATURES
-    tpg = gf * gf
+    """Returns (num_groups, K) uint32 bitmasks.
 
-    def kernel(origin_ref, img_ref, feat_ref, out_ref):
-        feat_b = feat_ref[0]
-        ox = origin_ref[0, 0]
-        oy = origin_ref[0, 1]
-        mx = feat_b[F_MEAN_X, :]
-        my = feat_b[F_MEAN_Y, :]
-        valid = feat_b[F_VALID, :] > 0.5
-        mask = jnp.zeros((K,), jnp.uint32)
-        for slot in range(tpg):  # static unroll: the 4 tile-check units
-            x0 = ox + (slot % gf) * tile_px
-            y0 = oy + (slot // gf) * tile_px
+    Group g has its top-left pixel at ((g % n_groups_x) * group,
+    (g // n_groups_x) * group), a function of the grid index; member tiles
+    outside the image get no bits. The grid runs over (group, lane block).
+    """
+    num_groups, F, K = feat.shape
+    assert F == NUM_FEATURES and num_groups == grid.num_groups
+    gf, tile_px = grid.gf, grid.tile
+    block = next(b for b in (512, 256, LANE) if K % b == 0)
+
+    def kernel(feat_ref, out_ref):
+        g = pl.program_id(0)
+        gx = g % grid.n_groups_x
+        gy = g // grid.n_groups_x
+        fb = feat_ref[0]                     # (F, block)
+        row = lambda f: fb[f:f + 1, :]       # (1, block)
+        mx, my = row(F_MEAN_X), row(F_MEAN_Y)
+        valid = row(F_VALID) > 0.5
+        mask = jnp.zeros((1, block), jnp.int32)
+        for slot in range(gf * gf):  # static unroll: the 4 tile-check units
+            tx = jnp.full((1, 1), gx * gf + slot % gf, jnp.int32)
+            ty = jnp.full((1, 1), gy * gf + slot // gf, jnp.int32)
+            x0 = (tx * tile_px).astype(jnp.float32)
+            y0 = (ty * tile_px).astype(jnp.float32)
             x1, y1 = x0 + tile_px, y0 + tile_px
             if method == "aabb":
-                hit = _aabb(mx, my, feat_b[F_RADIUS, :], x0, y0, x1, y1)
+                hit = _aabb(mx, my, row(F_RADIUS), x0, y0, x1, y1)
             elif method == "obb":
                 hit = _obb(
                     mx, my,
-                    feat_b[F_EIGVEC_X, :], feat_b[F_EIGVEC_Y, :],
-                    feat_b[F_EIGVAL_1, :], feat_b[F_EIGVAL_2, :],
+                    row(F_EIGVEC_X), row(F_EIGVEC_Y),
+                    row(F_EIGVAL_1), row(F_EIGVAL_2),
                     x0, y0, x1, y1,
                 )
             else:
                 hit = _ellipse(
                     mx, my,
-                    feat_b[F_CONIC_A, :], feat_b[F_CONIC_B, :], feat_b[F_CONIC_C, :],
+                    row(F_CONIC_A), row(F_CONIC_B), row(F_CONIC_C),
                     x0, y0, x1, y1,
                 )
-            hit = hit & valid & (img_ref[0, slot] > 0.5)
-            mask = mask | (hit.astype(jnp.uint32) << slot)
+            in_image = (tx < grid.n_tiles_x) & (ty < grid.n_tiles_y)
+            hit = hit & valid & in_image
+            mask = mask | (hit.astype(jnp.int32) << slot)
         out_ref[0] = mask
 
-    return pl.pallas_call(
+    interpret = resolve_interpret(interpret)
+    call = pl.pallas_call(
         kernel,
-        grid=(num_groups,),
-        in_specs=[
-            pl.BlockSpec((1, 2), lambda g: (g, 0)),
-            pl.BlockSpec((1, tpg), lambda g: (g, 0)),
-            pl.BlockSpec((1, F, K), lambda g: (g, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, K), lambda g: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_groups, K), jnp.uint32),
+        grid=(num_groups, K // block),
+        in_specs=[pl.BlockSpec((1, F, block), lambda g, k: (g, 0, k))],
+        out_specs=pl.BlockSpec((1, 1, block), lambda g, k: (g, 0, k)),
+        out_shape=jax.ShapeDtypeStruct((num_groups, 1, K), jnp.int32),
         interpret=interpret,
-    )(group_origin, tile_in_image.astype(jnp.float32), feat)
+    )
+    out = on_every_device(call, interpret)(feat)
+    return jax.lax.bitcast_convert_type(out.reshape(num_groups, K),
+                                        jnp.uint32)

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ops import resolve_interpret
+
 
 def _compare_exchange(keys, vals, dist: int, asc_mask):
     """One stage: partner = index XOR dist, ascending where asc_mask."""
@@ -57,7 +59,7 @@ def _bitonic_network(keys, vals, K: int):
 def bitonic_sort_kernel(
     keys: jnp.ndarray,   # (num_groups, K) float32, +inf padding
     payload: jnp.ndarray,  # (num_groups, K) float32 (bit-cast your ints)
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Returns (sorted_keys, permuted_payload), both (num_groups, K)."""
     num_groups, K = keys.shape
@@ -86,5 +88,5 @@ def bitonic_sort_kernel(
             jax.ShapeDtypeStruct((num_groups, K), jnp.float32),
             jax.ShapeDtypeStruct((num_groups, K), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(keys, payload)

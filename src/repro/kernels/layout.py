@@ -43,7 +43,7 @@ def pack_features(
 
     gauss_idx/entry_valid: (B, K). Invalid entries get opacity 0 (=> alpha 0 in
     the raster kernel) and valid flag 0. ``multiple`` sets the K padding
-    granularity — pass lcm(LANE, chunk) so any raster chunk size divides K_pad.
+    granularity: a multiple of LANE that the kernel's raster chunk divides.
 
     ``proj`` may be a flat ``Projected`` or a ``ShardedProjected`` kept in
     the per-shard layout (DESIGN.md §12): the gathers route through

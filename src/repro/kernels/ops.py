@@ -1,68 +1,80 @@
 """Thin public wrappers + layout glue for the GS-TG Pallas kernels.
 
-On CPU (this container) the kernels execute via Pallas interpret mode; on a
-real TPU backend the same code lowers to Mosaic. There is NO standalone
-kernel-path renderer here: the Pallas kernels are stage implementations of
-the unified engine — select them with ``RenderConfig(backend="pallas")`` and
-go through ``repro.core.pipeline.render`` (see core/stages.PallasBackend).
+On the CPU the kernels execute via Pallas interpret mode; on a TPU the same
+code lowers to Mosaic. There is NO standalone kernel-path renderer here: the
+Pallas kernels are stage implementations of the unified engine — select them
+with ``RenderConfig(backend="pallas")`` and go through
+``repro.core.pipeline.render`` (see core/stages.PallasBackend).
 Identification and group binning stay on the XLA sort substrate (DESIGN.md
-§2); this module only hosts the geometry/layout helpers those stages share.
+§2); this module hosts the interpret/compile decision, the multi-device
+wrapper and the layout helpers those stages share.
 """
 from __future__ import annotations
 
 import os
+import warnings
 
 import jax
 import jax.numpy as jnp
 
-# Pallas lowers natively on these platforms (Mosaic on TPU, Triton on GPU);
-# everywhere else the kernels run through the interpreter.
-_ACCEL_PLATFORMS = ("tpu", "gpu", "cuda", "rocm")
+# Pallas lowers natively (Mosaic) on the TPU; everywhere else the kernels
+# run through the interpreter.
+_ACCEL_PLATFORMS = ("tpu",)
 
 
 def default_interpret() -> bool:
     """Should Pallas kernels run in interpret mode?
 
     Resolution order (DESIGN.md §13, the real-hardware lane):
-      1. ``REPRO_PALLAS_INTERPRET`` env var — ``0/false/off`` forces
-         compiled kernels, anything else truthy forces the interpreter
-         (useful to keep interpret mode ON for debugging on a TPU host).
-      2. Platform auto-detect: compile on TPU/GPU, interpret elsewhere
-         (CPU has no Mosaic/Triton lowering).
+      1. ``REPRO_PALLAS_INTERPRET`` env var — ``0/false/off/no`` forces
+         compiled kernels, anything else non-blank forces the interpreter
+         (debugging on a TPU host). Forcing the interpreter on a TPU warns:
+         a chip run never drops to the interpreter silently.
+      2. Platform auto-detect: compile on TPU, interpret elsewhere (the CPU
+         has no Mosaic lowering).
 
     Per-call ``interpret=`` arguments on the kernel wrappers and
     ``PallasBackend(interpret=...)`` still override both.
     """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None and env.strip() != "":
-        return env.strip().lower() not in ("0", "false", "off", "no")
-    return jax.default_backend() not in _ACCEL_PLATFORMS
+    on_accel = jax.default_backend() in _ACCEL_PLATFORMS
+    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip()
+    if not env:
+        return not on_accel
+    interpret = env.lower() not in ("0", "false", "off", "no")
+    if interpret and on_accel:
+        warnings.warn(
+            f"REPRO_PALLAS_INTERPRET={env!r}: Pallas kernels run in the "
+            f"interpreter on {jax.default_backend()}, not compiled",
+            RuntimeWarning, stacklevel=2,
+        )
+    return interpret
 
 
-def group_origins(grid) -> jnp.ndarray:
-    g = jnp.arange(grid.num_groups, dtype=jnp.int32)
-    return jnp.stack(
-        [(g % grid.n_groups_x) * grid.group, (g // grid.n_groups_x) * grid.group],
-        axis=-1,
-    ).astype(jnp.float32)
+def resolve_interpret(interpret) -> bool:
+    """A kernel wrapper's ``interpret`` argument: None defers to
+    :func:`default_interpret`."""
+    return default_interpret() if interpret is None else bool(interpret)
 
 
-def tile_origins(grid) -> jnp.ndarray:
-    t = jnp.arange(grid.num_tiles, dtype=jnp.int32)
-    return jnp.stack(
-        [(t % grid.n_tiles_x) * grid.tile, (t // grid.n_tiles_x) * grid.tile],
-        axis=-1,
-    ).astype(jnp.float32)
+def on_every_device(kernel, interpret: bool):
+    """``kernel`` made runnable under the multi-device mesh in context.
 
+    XLA cannot partition a compiled Mosaic kernel. Under a mesh of more than
+    one device (``jax.set_mesh``; the engine handle sets its own) the kernel
+    therefore runs through ``shard_map`` on every device. Inside a camera
+    batch vmapped with ``spmd_axis_name`` (the handle's batch program names
+    the mesh's data axes) each device takes its own lanes; every other
+    operand axis is replicated, so on a 'model' axis each device rasterizes
+    every group. Interpreted kernels are plain XLA and are returned
+    unchanged.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if interpret or mesh.empty or mesh.size == 1:
+        return kernel
+    from jax.sharding import PartitionSpec
 
-def tiles_in_image(grid) -> jnp.ndarray:
-    """(num_groups, tpg) bool: member tile lies inside the image."""
-    g = jnp.arange(grid.num_groups, dtype=jnp.int32)[:, None]
-    s = jnp.arange(grid.tiles_per_group, dtype=jnp.int32)[None, :]
-    gf = grid.gf
-    tx = (g % grid.n_groups_x) * gf + s % gf
-    ty = (g // grid.n_groups_x) * gf + s // gf
-    return (tx < grid.n_tiles_x) & (ty < grid.n_tiles_y)
+    return jax.shard_map(kernel, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec(), check_vma=False)
 
 
 def sort_groups_bitonic(depth_keys, payload_idx, interpret=None):
@@ -77,7 +89,6 @@ def sort_groups_bitonic(depth_keys, payload_idx, interpret=None):
     """
     from repro.kernels.bitonic_sort import bitonic_sort_kernel
 
-    interpret = default_interpret() if interpret is None else interpret
     payload_f = payload_idx.astype(jnp.float32)  # indices < 2^24: exact in f32
     k, v = bitonic_sort_kernel(depth_keys, payload_f, interpret=interpret)
     return k, v.astype(jnp.int32)

@@ -34,6 +34,34 @@ QMAX = 9.0
 SIGMA_CUT = 3.0
 
 
+def group_origins(grid) -> jnp.ndarray:
+    """(num_groups, 2) float32 pixel origin of each group."""
+    g = jnp.arange(grid.num_groups, dtype=jnp.int32)
+    return jnp.stack(
+        [(g % grid.n_groups_x) * grid.group, (g // grid.n_groups_x) * grid.group],
+        axis=-1,
+    ).astype(jnp.float32)
+
+
+def tile_origins(grid) -> jnp.ndarray:
+    """(num_tiles, 2) float32 pixel origin of each tile."""
+    t = jnp.arange(grid.num_tiles, dtype=jnp.int32)
+    return jnp.stack(
+        [(t % grid.n_tiles_x) * grid.tile, (t // grid.n_tiles_x) * grid.tile],
+        axis=-1,
+    ).astype(jnp.float32)
+
+
+def tiles_in_image(grid) -> jnp.ndarray:
+    """(num_groups, tpg) bool: member tile lies inside the image."""
+    g = jnp.arange(grid.num_groups, dtype=jnp.int32)[:, None]
+    s = jnp.arange(grid.tiles_per_group, dtype=jnp.int32)[None, :]
+    gf = grid.gf
+    tx = (g % grid.n_groups_x) * gf + s % gf
+    ty = (g // grid.n_groups_x) * gf + s // gf
+    return (tx < grid.n_tiles_x) & (ty < grid.n_tiles_y)
+
+
 def _pixels(origin, tile_px):
     lin = jnp.arange(tile_px * tile_px, dtype=jnp.float32)
     px = origin[0] + jnp.mod(lin, tile_px) + 0.5

@@ -8,13 +8,7 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """axis_types=Auto on jax versions that have it; {} on older releases
-    (pre-AxisType jax treats every mesh axis as Auto already)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,28 +16,36 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis across the DCI: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(_AUTO,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over the locally available devices (tests/examples)."""
     return jax.make_mesh(
-        (data, model), ("data", "model"), **_axis_type_kwargs(2)
+        (data, model), ("data", "model"), axis_types=(_AUTO,) * 2
     )
 
 
 def render_mesh_shards(n_devices: int, scene_shards: int) -> int:
     """The physical shard count a render mesh over ``n_devices`` can realize:
-    ``scene_shards`` when it divides the device count, else 1 (the shard axis
-    stays logical — correct results, no per-device memory saving). THE single
-    fallback policy: serving/sharded.py, serving/server.py,
+    ``scene_shards`` when it divides the device count. Otherwise the shard
+    axis stays logical (1: correct results, no per-device memory saving) on
+    the CPU, and on an accelerator the request fails: a chip run that asks
+    for physical shards never silently gets a replicated layout. THE single
+    shard policy: serving/sharded.py, serving/server.py,
     launch/render_serve.py and the benchmarks all route through it."""
-    if scene_shards > 1 and n_devices % scene_shards == 0:
-        return scene_shards
+    if scene_shards <= 1 or n_devices % scene_shards == 0:
+        return max(scene_shards, 1)
+    if jax.default_backend() != "cpu":
+        raise ValueError(
+            f"scene_shards={scene_shards} does not divide the "
+            f"{n_devices} {jax.default_backend()} devices; a physical "
+            f"shard layout needs a divisor of the device count"
+        )
     return 1
 
 
-def make_render_mesh(devices: int | None = None, scene_shards: int = 1):
+def make_render_mesh(devices=None, scene_shards: int = 1):
     """Render-serving mesh (serving/sharded.py).
 
     ``scene_shards == 1``: the classic 1-D ('data',) pure-DP mesh — rendering
@@ -54,17 +56,24 @@ def make_render_mesh(devices: int | None = None, scene_shards: int = 1):
     larger than a single device's replicated budget render at all.
 
     ``devices=None`` takes every local device (the single-host serving
-    deployment); an explicit count takes a prefix (tests pin 1)."""
-    n = len(jax.devices()) if devices is None else devices
-    if n <= 0:
-        raise ValueError(f"render mesh needs >= 1 device, got {n}")
+    deployment); an int takes a prefix (tests pin 1); a sequence of devices
+    is used as given (one in-process replica per chip)."""
+    if devices is None:
+        devs = jax.devices()
+    elif isinstance(devices, int):
+        devs = jax.devices()[:max(devices, 0)]
+    else:
+        devs = list(devices)
+    n = len(devs)
+    if n == 0:
+        raise ValueError(f"render mesh needs >= 1 device, got {devices!r}")
     if scene_shards <= 1:
-        return jax.make_mesh((n,), ("data",), **_axis_type_kwargs(1))
-    if n % scene_shards:
-        raise ValueError(
-            f"scene_shards={scene_shards} must divide the device count {n}"
-        )
-    return jax.make_mesh(
-        (n // scene_shards, scene_shards), ("data", "model"),
-        **_axis_type_kwargs(2),
-    )
+        shape, axes = (n,), ("data",)
+    else:
+        if n % scene_shards:
+            raise ValueError(
+                f"scene_shards={scene_shards} must divide the device count {n}"
+            )
+        shape, axes = (n // scene_shards, scene_shards), ("data", "model")
+    return jax.make_mesh(shape, axes, devices=devs,
+                         axis_types=(_AUTO,) * len(axes))

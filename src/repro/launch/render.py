@@ -25,6 +25,7 @@ from repro import engine
 from repro.core.cost_model import GSTG_ASIC, estimate
 from repro.core.pipeline import RenderConfig, render_cache_info
 from repro.obs import get_tracer, trace_env_enabled
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _stage_table(events) -> str:
@@ -83,6 +84,7 @@ def main():
                          "measured render's per-stage device spans; implies "
                          "fenced per-stage timing (DESIGN.md §14)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     backend = "pallas" if args.use_kernels else args.backend
     # Fenced per-stage timing: each backend stage becomes its own jit'd

@@ -14,6 +14,10 @@ flag-killed (inproc) mid-load and the run must still complete every
 request — the CI smoke in scripts/check.sh gates on exactly that, plus
 ``--parity-check`` proving failover is invisible in the pixels.
 
+One process per chip: on a TPU this process holds the devices, so the fleet
+must be ``--inproc`` (worker i on its own device where the host has enough);
+subprocess workers are refused there.
+
 Exits non-zero if any request was lost, p99 is not finite, parity fails,
 or an induced kill produced no failover.
 """
@@ -34,8 +38,10 @@ def parse_args(argv=None):
                          "worker forces this count in its own runtime; "
                          "inproc workers share one runtime of this size)")
     ap.add_argument("--inproc", action="store_true",
-                    help="in-process workers (one shared jax runtime) "
-                         "instead of subprocess children")
+                    help="in-process workers (one shared jax runtime, each "
+                         "worker on its own devices where the host has "
+                         "enough) instead of subprocess children; required "
+                         "on a TPU, where one process holds the chips")
     ap.add_argument("--backend", default="reference",
                     choices=["reference", "pallas"])
     ap.add_argument("--scenes", default="train,truck")
@@ -116,6 +122,17 @@ def main(argv=None):
     from repro.obs import get_registry, get_tracer
     from repro.serving.queue import RenderRequest
     from repro.serving.server import poisson_arrivals
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    if jax.default_backend() != "cpu" and not args.inproc:
+        # This process now holds the chip(s): a subprocess worker could not
+        # open them, and two processes never share one chip.
+        print(f"render_gateway: subprocess workers cannot share the "
+              f"{jax.default_backend()} devices this process holds; pass "
+              f"--inproc (one in-process worker per device)",
+              file=sys.stderr)
+        return 2
 
     tracer = get_tracer()
     if args.trace_json or args.metrics_json:
@@ -137,21 +154,28 @@ def main(argv=None):
     if args.inproc:
         from repro.gateway.worker import InprocWorker
 
-        n_dev = len(jax.devices())
-        use_dev = min(dpw, n_dev)
-        mesh = make_render_mesh(use_dev, render_mesh_shards(use_dev, shards))
+        devs = jax.devices()
+        use_dev = min(dpw, len(devs))
+
+        def worker_mesh(i):
+            # Worker i takes the next use_dev devices, wrapping around when
+            # workers x devices-per-worker exceeds the host: on a four-chip
+            # host four one-chip workers each get their own chip.
+            own = [devs[(i * use_dev + k) % len(devs)] for k in range(use_dev)]
+            return make_render_mesh(own, render_mesh_shards(use_dev, shards))
+
         scenes = {
             sid: scene_like_paper(jax.random.key(i), sid, args.gaussians)
             for i, sid in enumerate(scene_ids)
         }
         workers = [
             InprocWorker(
-                wid, scenes, mesh=mesh,
+                wid, scenes, mesh=worker_mesh(i),
                 max_batch=args.max_batch, max_wait=args.max_wait,
                 queue_depth=args.worker_queue_depth, scene_shards=shards,
                 device_budget_mb=args.device_budget_mb,
             )
-            for wid in worker_ids
+            for i, wid in enumerate(worker_ids)
         ]
     else:
         from repro.gateway.transport import SubprocessWorker, worker_argv

@@ -141,6 +141,9 @@ def main(argv=None):
     from repro.obs import get_registry, get_tracer, trace_env_enabled
     from repro.serving.queue import RenderRequest
     from repro.serving.server import RenderServer, poisson_arrivals
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     # Asking for a trace (or metrics) file opts the process into span
     # recording; REPRO_TRACE=1 additionally turns on fenced per-stage device

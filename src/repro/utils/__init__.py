@@ -1,4 +1,5 @@
 from repro.utils.misc import (
+    HIGHEST,
     cdiv,
     pytree_bytes,
     pytree_count,
@@ -8,6 +9,7 @@ from repro.utils.misc import (
 )
 
 __all__ = [
+    "HIGHEST",
     "cdiv",
     "round_up",
     "pytree_bytes",

@@ -5,6 +5,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# Float32 matmuls on every platform: the TPU's default precision rounds
+# float32 operands to bfloat16. The render path's geometry and colour sums
+# pass this explicitly.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)

@@ -7,8 +7,9 @@ change (a numerics-affecting feature with a reviewed diff):
 
 Each fixture ``<name>.npz`` is fully self-contained: the scene ARRAYS are
 stored (not a PRNG seed — a jax.random implementation change must not be
-able to move the pin), together with the camera, the RenderConfig kwargs,
-and the rendered image per backend. ``checksums.json`` pins the sha256 of
+able to move the pin; regeneration re-renders over the stored arrays and
+draws a scene only for a fixture that has no file yet), together with the
+camera, the RenderConfig kwargs, and the rendered image per backend. ``checksums.json`` pins the sha256 of
 every stored array so accidental regeneration or fixture drift is loud in
 review. The images are tiny (64px-side scenes) to keep the fixtures a few
 tens of KB and the renders inside the fast test lane.
@@ -93,15 +94,25 @@ def render_one_jit(scene, cam, cfg):
 def build_fixture(name, scene_kw, cam_kw, cfg_kw):
     import jax
 
-    from repro.core import make_camera, random_scene
+    from repro.core import GaussianScene, make_camera, random_scene
     from repro.core.pipeline import RenderConfig
 
-    scene = random_scene(
-        jax.random.key(scene_kw["seed"]),
-        scene_kw["num_gaussians"],
-        extent=scene_kw["extent"],
-        sh_degree=scene_kw["sh_degree"],
-    )
+    path = HERE / f"{name}.npz"
+    if path.exists():
+        # Re-render over the committed scene arrays: the pinned inputs stay
+        # put even when jax.random draws differently.
+        data = np.load(path)
+        scene = GaussianScene(**{
+            f.name: data[f"scene_{f.name}"]
+            for f in dataclasses.fields(GaussianScene)
+        })
+    else:
+        scene = random_scene(
+            jax.random.key(scene_kw["seed"]),
+            scene_kw["num_gaussians"],
+            extent=scene_kw["extent"],
+            sh_degree=scene_kw["sh_degree"],
+        )
     cam = make_camera(**cam_kw)
     payload = {
         f"scene_{f.name}": np.asarray(getattr(scene, f.name))

@@ -1,24 +1,15 @@
-"""The real-hardware Pallas lane (DESIGN.md §13).
-
-Two halves:
-
-  * unit tests for ``kernels.ops.default_interpret`` — the env-var override
-    and platform auto-detect that decide whether Pallas kernels interpret
-    (CPU, this container) or compile (Mosaic on TPU, Triton on GPU);
-  * ``@pytest.mark.accel`` parity tests that only run when jax actually has
-    an accelerator backend: the COMPILED pallas lane against the reference
-    backend, through the same engine-handle path the interpret-mode parity
-    suite uses. On CPU they skip — ``scripts/check.sh --accel`` is the hook
-    that selects them the day real hardware appears.
+"""The real-hardware Pallas lane (DESIGN.md §13): unit tests for
+``kernels.ops.default_interpret`` — the env-var override and platform
+auto-detect that decide whether Pallas kernels interpret (CPU) or compile
+(Mosaic on TPU). The compiled kernels themselves are checked by
+tests/test_tpu_compile.py (compiled for a described v5e chip) and on the
+chip by chip_smoke.py.
 """
 import jax
 import numpy as np
 import pytest
 
 from repro.kernels.ops import _ACCEL_PLATFORMS, default_interpret
-
-ON_ACCEL = jax.default_backend() in _ACCEL_PLATFORMS
-
 
 # -- default_interpret resolution --------------------------------------------
 
@@ -61,47 +52,14 @@ def test_explicit_arg_still_overrides(monkeypatch, tiny_scene):
     assert list(np.asarray(v)[0, :3]) == [1, 2, 0]
 
 
-# -- compiled-lane parity (auto-skipped off-accelerator) ----------------------
+def test_env_interpreter_on_chip_warns(monkeypatch):
+    """Forcing the interpreter on a TPU is allowed for debugging, but never
+    silent: a chip run must not drop to interpret mode unnoticed."""
+    from repro.kernels import ops
 
-
-@pytest.mark.accel
-@pytest.mark.skipif(
-    not ON_ACCEL,
-    reason=f"jax backend {jax.default_backend()!r} has no native Pallas "
-           f"lowering; compiled-lane parity needs TPU/GPU",
-)
-def test_compiled_pallas_matches_reference(monkeypatch, tiny_scene, cam128):
-    """The whole point of the lane: the COMPILED kernels (not the
-    interpreter) must agree with the reference backend on real hardware."""
-    from repro import engine
-    from repro.core.pipeline import RenderConfig
-
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
     assert default_interpret() is False
-    kw = dict(tile=16, group=64, group_capacity=256, tile_capacity=256,
-              mode="gstg", span=6)
-    with engine.open(tiny_scene, RenderConfig(backend="reference", **kw)) as rr, \
-            engine.open(tiny_scene, RenderConfig(backend="pallas", **kw)) as rp:
-        ref = np.asarray(rr.render(cam128).image)
-        pal = np.asarray(rp.render(cam128).image)
-    # cross-substrate fp tolerance (same bound as the interpret-mode parity
-    # suite); bitwise is not expected across compilers
-    assert np.allclose(ref, pal, atol=1e-5, rtol=1e-5)
-
-
-@pytest.mark.accel
-@pytest.mark.skipif(
-    not ON_ACCEL,
-    reason="bitonic kernel compiled-lane check needs TPU/GPU",
-)
-def test_compiled_bitonic_sort_matches_xla(monkeypatch):
-    import jax.numpy as jnp
-
-    from repro.kernels.ops import sort_groups_bitonic
-
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    keys = jax.random.uniform(jax.random.key(0), (8, 64))
-    keys = jnp.where(keys > 0.9, jnp.inf, keys)
-    idx = jnp.broadcast_to(jnp.arange(64, dtype=jnp.int32), (8, 64))
-    k, _ = sort_groups_bitonic(keys, idx)  # interpret=None -> compiled here
-    assert np.allclose(np.asarray(k), np.sort(np.asarray(keys), axis=-1))
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.warns(RuntimeWarning, match="interpreter"):
+        assert default_interpret() is True

@@ -80,6 +80,9 @@ def test_signature_keys_on_geometry_resolution_backend(tiny_scene,
         tiny_scene, 128, 128, _cfg(mode="tile_baseline")
     ) != base
     assert autotune_signature(small_scene, 128, 128, _cfg()) != base
+    # the device closes the key: CPU winners never steer a chip run
+    dev = jax.devices()[0]
+    assert base[-2:] == (dev.platform, dev.device_kind)
     # same geometry, different parameter values -> SAME key (a retrained
     # checkpoint reuses the tune)
     clone = dataclasses.replace(

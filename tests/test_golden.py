@@ -93,11 +93,20 @@ GOLDEN_CASES = [
 ]
 
 
+# D=2 compiles a different program (per-shard frontend + merge, sharded
+# gathers) and XLA fuses its float32 projection differently. One ulp of a
+# projected mean at ~100 px is ~7.6e-6 px, which moves an alpha (and so a
+# pixel) by up to ~1e-5; 1.2e-7 is seen here. A real numerics change moves
+# pixels by orders of magnitude more.
+SHARDED_ATOL = 1e-5
+
+
 @pytest.mark.parametrize("name,backend,shards", GOLDEN_CASES)
 def test_golden_image(name, backend, shards, checksums):
-    """Bitwise reproduction of the committed golden image, per backend, at
-    D in {1, 2} — D=2 exercises the per-shard frontend + merge + the
-    feature-sharded gathers and must land on the SAME image."""
+    """The committed golden image, per backend, at D in {1, 2}. D=1 runs
+    the generator's own program and must reproduce it bitwise; D=2
+    exercises the per-shard frontend + merge + the feature-sharded gathers
+    and must land within SHARDED_ATOL with the SAME integer counters."""
     from repro.core.pipeline import RenderConfig
 
     data, scene, cam, cfg_kw = _load(name)
@@ -107,6 +116,18 @@ def test_golden_image(name, backend, shards, checksums):
     golden = data[f"image_{backend}"]
     assert img.shape == golden.shape and img.dtype == golden.dtype
     assert int(np.asarray(out.stats.overflow)) == 0
+    if shards > 1:
+        base = golden_generate.render_one_jit(
+            scene, cam, RenderConfig(backend=backend, **cfg_kw))
+        for field in vars(base.stats):
+            assert np.array_equal(np.asarray(getattr(out.stats, field)),
+                                  np.asarray(getattr(base.stats, field))), (
+                f"{name}/{backend}/D{shards}: counter {field} differs")
+        diff = np.abs(img - golden)
+        assert diff.max() <= SHARDED_ATOL, (
+            f"{name}/{backend}/D{shards}: max abs diff {diff.max():.3e} "
+            f"over {(diff > 0).sum()} channels exceeds {SHARDED_ATOL:.1e}")
+        return
     if not (img == golden).all():
         diff = np.abs(img - golden)
         pytest.fail(

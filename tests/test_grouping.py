@@ -82,10 +82,14 @@ def test_sort_op_count_model():
 def test_grid_spec_validation():
     import pytest
 
-    with pytest.raises(ValueError):
-        GridSpec(100, 100, 16, 64)  # not tile-divisible
+    # A native resolution that is not tile-divisible gets partial edge
+    # tiles and groups (cdiv); the stages crop them.
+    g = GridSpec(100, 100, 16, 64)
+    assert (g.n_tiles_x, g.n_groups_x, g.num_tiles) == (7, 2, 49)
     with pytest.raises(ValueError):
         GridSpec(128, 128, 16, 40)  # group not multiple of tile
+    with pytest.raises(ValueError):
+        GridSpec(0, 128, 16, 64)    # empty image
 
 
 # ---------------------------------------------------------------------------

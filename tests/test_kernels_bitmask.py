@@ -7,7 +7,7 @@ from repro.core import make_camera, random_scene
 from repro.core.bitmask import generate_bitmasks
 from repro.core.grouping import GridSpec, bin_pairs, identify
 from repro.core.projection import project
-from repro.kernels import ops, ref as kref
+from repro.kernels import ref as kref
 from repro.kernels.bitmask_gen import bitmask_kernel
 from repro.kernels.layout import pack_features
 
@@ -31,11 +31,9 @@ def _setup(method, gf, seed=0):
 @pytest.mark.parametrize("gf", [2, 4])
 def test_bitmask_kernel_vs_oracle(method, gf):
     proj, grid, gtable, feat = _setup(method, gf)
-    origins = ops.group_origins(grid)
-    in_img = ops.tiles_in_image(grid)
-    got = bitmask_kernel(
-        feat, origins, in_img, grid.tile, gf, method=method, interpret=True
-    )
+    origins = kref.group_origins(grid)
+    in_img = kref.tiles_in_image(grid)
+    got = bitmask_kernel(feat, grid, method=method, interpret=True)
     want = kref.ref_bitmask(feat, origins, in_img, grid.tile, gf, method)
     assert (np.asarray(got) == np.asarray(want)).all()
 
@@ -45,13 +43,5 @@ def test_bitmask_kernel_vs_core(method):
     """Kernel masks == core generate_bitmasks (the XLA substrate path)."""
     proj, grid, gtable, feat = _setup(method, 4, seed=3)
     core = generate_bitmasks(proj, gtable, grid, method)
-    got = bitmask_kernel(
-        feat,
-        ops.group_origins(grid),
-        ops.tiles_in_image(grid),
-        grid.tile,
-        4,
-        method=method,
-        interpret=True,
-    )
+    got = bitmask_kernel(feat, grid, method=method, interpret=True)
     assert (np.asarray(got) == np.asarray(core.masks)).all()

@@ -9,7 +9,7 @@ from repro.core.bitmask import compact_tiles, generate_bitmasks
 from repro.core.grouping import GridSpec, bin_pairs, identify
 from repro.core.pipeline import RenderConfig, render
 from repro.core.projection import project
-from repro.kernels import ops, ref as kref
+from repro.kernels import ref as kref
 from repro.kernels.layout import pack_features
 from repro.kernels.raster_tile import raster_group_fused_kernel, raster_tile_kernel
 
@@ -34,19 +34,22 @@ def _tables(seed=1, w=96, h=96, tile=16, group=64, gcap=256, tcap=128):
     "tile,chunk",
     [
         # Fast lane keeps the default tile=16 layout; the other tile/chunk
-        # layouts cover lane/packing variants and ride the slow lane.
-        (16, 64),
-        pytest.param(8, 64, marks=pytest.mark.slow),
-        pytest.param(16, 128, marks=pytest.mark.slow),
+        # layouts (chunks are whole multiples of the 128-lane width; 32 px
+        # tiles span several pixel blocks) ride the slow lane.
+        (16, 128),
+        pytest.param(8, 128, marks=pytest.mark.slow),
+        pytest.param(16, 256, marks=pytest.mark.slow),
         pytest.param(32, 128, marks=pytest.mark.slow),
     ],
 )
 def test_raster_tile_kernel_vs_oracle(tile, chunk):
     group = tile * 4
     proj, grid, _, _, ttable = _tables(tile=tile, group=group, tcap=128)
-    feat = pack_features(proj, ttable.gauss_idx, ttable.entry_valid)
-    origins = ops.tile_origins(grid)
-    out_k = raster_tile_kernel(feat, origins, tile, chunk=chunk, interpret=True)
+    feat = pack_features(proj, ttable.gauss_idx, ttable.entry_valid,
+                         multiple=chunk)
+    origins = kref.tile_origins(grid)
+    out_k = raster_tile_kernel(feat, grid.n_tiles_x, tile, chunk=chunk,
+                               interpret=True)
     out_r = kref.ref_raster_tiles(feat, origins, tile)
     np.testing.assert_allclose(
         np.asarray(out_k), np.asarray(out_r), atol=3e-6, rtol=1e-5
@@ -58,9 +61,10 @@ def test_fused_kernel_vs_oracle(gf):
     tile = 16
     proj, grid, gtable, masks, _ = _tables(tile=tile, group=tile * gf)
     feat = pack_features(proj, gtable.gauss_idx, gtable.entry_valid)
-    origins = ops.group_origins(grid)
+    origins = kref.group_origins(grid)
     out_k = raster_group_fused_kernel(
-        feat, masks.masks, origins, tile, gf, chunk=128, interpret=True
+        feat, masks.masks, grid.n_groups_x, tile, gf, chunk=128,
+        interpret=True
     )
     out_r = kref.ref_raster_group_fused(feat, masks.masks, origins, tile, gf)
     np.testing.assert_allclose(
@@ -97,7 +101,7 @@ def test_raster_kernel_empty_tiles():
         lengths=jnp.zeros_like(ttable.lengths),
     )
     feat = pack_features(proj, empty.gauss_idx, empty.entry_valid)
-    out = raster_tile_kernel(feat, ops.tile_origins(grid), 16, chunk=128,
+    out = raster_tile_kernel(feat, grid.n_tiles_x, 16, chunk=128,
                              interpret=True)
     out = np.asarray(out)
     assert np.allclose(out[:, :3, :], 0.0)

@@ -423,8 +423,13 @@ for mode, backend in %(combos)s:
     mesh = make_render_mesh(%(devices)d, scene_shards=%(shards)d)
     sh = render_batch_sharded(scene, cams, cfg, mesh=mesh,
                               scene_shards=%(shards)d)
-    if not (np.asarray(rep.image) == np.asarray(sh.image)).all():
-        failures.append((mode, backend, "image"))
+    # Two different programs (replicated render_batch vs the sharded mesh
+    # program) fuse the float32 projection differently: one ulp of a
+    # projected mean at ~100 px (~7.6e-6 px) moves a pixel by up to ~1e-5.
+    # Counters stay exact.
+    diff = np.abs(np.asarray(rep.image) - np.asarray(sh.image)).max()
+    if not diff <= 1e-5:
+        failures.append((mode, backend, "image", float(diff)))
     for name in vars(rep.stats):
         if not (np.asarray(getattr(rep.stats, name))
                 == np.asarray(getattr(sh.stats, name))).all():
@@ -487,8 +492,8 @@ print(json.dumps({"failures": failures}))
 @pytest.mark.parametrize("devices,shards", [(2, 2), (4, 4), (4, 2)])
 def test_scene_sharded_virtual_devices(devices, shards):
     """Physically sharded over 2/4 virtual host devices (2-D (data, model)
-    mesh, subprocess so the XLA flag stays contained): bitwise image +
-    identical counters vs the replicated path, gstg and tile_baseline —
+    mesh, subprocess so the XLA flag stays contained): image within 1e-5
+    (float32 fusion) + identical counters vs the replicated path, gstg and tile_baseline —
     pallas included on the 2-device mesh (interpret mode is slow)."""
     combos = [("gstg", "reference"), ("tile_baseline", "reference")]
     if devices == 2:
