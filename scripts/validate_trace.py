@@ -15,7 +15,10 @@ Cross-checks the three observability surfaces one ``repro.launch
   * trace <-> metrics <-> summary consistency: completed requests and
     dispatched batches must agree between the request/serve spans, the
     ``serving.*`` counters + latency histogram, and the stats summary
-    embedded under the trace's ``"summary"`` key;
+    embedded under the trace's ``"summary"`` key; every dispatch holds its
+    nested ``serve/launch``, ``serve/device_wait`` and ``serve/fetch``
+    spans, and every request its ``request/device`` and ``request/fetch``
+    phases;
   * residency paging (DESIGN.md §17): ``residency/page_in|page_out``
     span counts must equal the ``residency.page_ins_total|page_outs_total``
     counters (span + counter are recorded in the same critical section),
@@ -33,6 +36,8 @@ import sys
 from repro.obs import validate_chrome_trace
 
 MIN_STAGE_NAMES = 7
+# The live phases nested under every serve/dispatch (and engine/dispatch).
+DISPATCH_PHASES = ("serve/launch", "serve/device_wait", "serve/fetch")
 
 
 def validate_residency(xs: list, counters: dict) -> list:
@@ -177,6 +182,21 @@ def validate(trace_doc: dict, metrics_doc: dict) -> list:
         if got != batches:
             errs.append(f"{label} = {got} but summary.batches = {batches}")
 
+    # Each dispatch (server or engine handle) splits into its three live
+    # phases, each nested in it on the dispatching thread's lane.
+    outer = [e for e in xs
+             if e.get("name") in ("serve/dispatch", "engine/dispatch")]
+    for phase in DISPATCH_PHASES:
+        inside = sum(
+            1 for e in xs if e.get("name") == phase and any(
+                o["tid"] == e["tid"] and o["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= o["ts"] + o["dur"] + 0.01
+                for o in outer))
+        if inside != len(outer):
+            errs.append(
+                f"{phase} spans nested in a dispatch = {inside} but "
+                f"dispatches = {len(outer)}")
+
     # Stream/speculation consistency (DESIGN.md §15): every stream frame
     # records exactly one spec/verify span (the exact-reuse cache decision),
     # so the span count must equal the stream hit+miss counter totals; every
@@ -207,13 +227,14 @@ def validate(trace_doc: dict, metrics_doc: dict) -> list:
     # Every request span must carry its device phase — a request that
     # completed without a dispatch/device_done stamp pair means a lifecycle
     # stamp went missing.
-    device_ids = {e["args"]["request_id"] for e in xs
-                  if e.get("cat") == "request"
-                  and e.get("name") == "request/device"}
-    missing = req_ids - device_ids
-    if missing:
-        errs.append(f"{len(missing)} request(s) have no request/device span: "
-                    f"{sorted(missing)[:5]}")
+    # The same for the image copy to the host (device_done/fetched).
+    for phase in ("request/device", "request/fetch"):
+        ids = {e["args"]["request_id"] for e in xs
+               if e.get("cat") == "request" and e.get("name") == phase}
+        missing = req_ids - ids
+        if missing:
+            errs.append(f"{len(missing)} request(s) have no {phase} span: "
+                        f"{sorted(missing)[:5]}")
 
     errs.extend(validate_residency(xs, counters))
     return errs

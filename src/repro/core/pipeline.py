@@ -64,6 +64,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 import warnings
 from typing import Any, List, Optional, Sequence, Union
 
@@ -166,6 +167,32 @@ class FrontendResult:
     span_overflow: jnp.ndarray       # candidate-window dropped bins
 
 
+# Every stage call of the fused program runs under ``jax.named_scope(
+# "gstg/<stage>")``, so each op of the compiled program carries its stage in
+# its ``op_name`` metadata and a device trace can be split by stage. Scopes
+# change metadata only: the compiled program is otherwise the same.
+STAGE_SCOPE = "gstg"
+STAGES = ("project", "identify", "bin", "merge", "bitmask", "compact",
+          "raster")
+
+
+def stage_scope(stage: str):
+    """The named scope of one pipeline stage (one of ``STAGES``)."""
+    return jax.named_scope(f"{STAGE_SCOPE}/{stage}")
+
+
+def stage_of(op_name: str) -> Optional[str]:
+    """The stage named in an op's ``op_name`` metadata, or None when the op
+    ran outside every stage scope. A transformation wraps the scopes it
+    traced through (``jit(one)/vmap(gstg/bin)/sort``)."""
+    m = _STAGE_RE.search(op_name)
+    return m.group(1) if m else None
+
+
+_STAGE_RE = re.compile(
+    rf"(?:^|[/(]){STAGE_SCOPE}/({'|'.join(STAGES)})(?=[/)]|$)")
+
+
 def _grid(cam, cfg: RenderConfig) -> GridSpec:
     return GridSpec(
         width=cam.width,
@@ -257,9 +284,12 @@ def _frontend(
     f32 counters are approximate-but-monotone on BOTH paths).
     """
     if isinstance(scene, GaussianScene):
-        proj = backend.project(scene, cam)
-        pairs = backend.identify(proj, grid, level, method)
-        table = backend.bin(pairs, num_bins, capacity)
+        with stage_scope("project"):
+            proj = backend.project(scene, cam)
+        with stage_scope("identify"):
+            pairs = backend.identify(proj, grid, level, method)
+        with stage_scope("bin"):
+            table = backend.bin(pairs, num_bins, capacity)
         return proj, table, (
             pairs.n_candidate_tests, pairs.n_pairs, pairs.n_span_overflow
         )
@@ -269,33 +299,42 @@ def _frontend(
         # Timed mode: each vmapped stage is one fenced jit(vmap) program —
         # the per-shard calls below run inside the vmap trace, where fences
         # would no-op (core/stages.py::TimedBackend).
-        proj_s = backend.project_shards(scene.shards, cam)
-        pairs_s = backend.identify_shards(proj_s, grid, level, method)
-        tables_s = backend.bin_shards(pairs_s, num_bins, capacity)
+        with stage_scope("project"):
+            proj_s = backend.project_shards(scene.shards, cam)
+        with stage_scope("identify"):
+            pairs_s = backend.identify_shards(proj_s, grid, level, method)
+        with stage_scope("bin"):
+            tables_s = backend.bin_shards(pairs_s, num_bins, capacity)
     else:
-        proj_s = jax.vmap(lambda s: backend.project(s, cam))(scene.shards)
-        pairs_s = jax.vmap(
-            lambda p: backend.identify(p, grid, level, method)
-        )(proj_s)
-        tables_s = jax.vmap(lambda p: backend.bin(p, num_bins, capacity))(pairs_s)
+        with stage_scope("project"):
+            proj_s = jax.vmap(lambda s: backend.project(s, cam))(scene.shards)
+        with stage_scope("identify"):
+            pairs_s = jax.vmap(
+                lambda p: backend.identify(p, grid, level, method)
+            )(proj_s)
+        with stage_scope("bin"):
+            tables_s = jax.vmap(
+                lambda p: backend.bin(p, num_bins, capacity)
+            )(pairs_s)
 
-    # Shard-local -> global gaussian indices: the canonical layout is
-    # gaussian-contiguous, so shard d starts at d * shard_size.
-    offsets = (jnp.arange(D, dtype=jnp.int32) * shard_size)[:, None, None]
-    gauss_idx = jnp.where(
-        tables_s.entry_valid, tables_s.gauss_idx + offsets, 0
-    )
-    # Merge keys gathered SHARD-LOCALLY (each shard reads only its own
-    # rows): bitwise-equal to the flat proj.depth[global_idx] gather because
-    # flat[d * Ns + l] == proj_s.depth[d, l].
-    depth = jnp.where(
-        tables_s.entry_valid,
-        jax.vmap(lambda p, t: p.depth[t.gauss_idx])(proj_s, tables_s),
-        jnp.inf,
-    )
-    table = backend.merge(
-        dataclasses.replace(tables_s, gauss_idx=gauss_idx), depth
-    )
+    with stage_scope("merge"):
+        # Shard-local -> global gaussian indices: the canonical layout is
+        # gaussian-contiguous, so shard d starts at d * shard_size.
+        offsets = (jnp.arange(D, dtype=jnp.int32) * shard_size)[:, None, None]
+        gauss_idx = jnp.where(
+            tables_s.entry_valid, tables_s.gauss_idx + offsets, 0
+        )
+        # Merge keys gathered SHARD-LOCALLY (each shard reads only its own
+        # rows): bitwise-equal to the flat proj.depth[global_idx] gather
+        # because flat[d * Ns + l] == proj_s.depth[d, l].
+        depth = jnp.where(
+            tables_s.entry_valid,
+            jax.vmap(lambda p, t: p.depth[t.gauss_idx])(proj_s, tables_s),
+            jnp.inf,
+        )
+        table = backend.merge(
+            dataclasses.replace(tables_s, gauss_idx=gauss_idx), depth
+        )
     if feature_gather == "flat":
         proj = jax.tree.map(
             lambda x: x.reshape(D * shard_size, *x.shape[2:]), proj_s
@@ -411,26 +450,27 @@ def _run_backend(
         #    no data dependence and schedule freely (table order does not
         #    affect masks: masks are per-entry — which is also why bitmasks
         #    need no cross-shard pass: they run on the already-merged table).
-        masks = backend.bitmasks(
-            proj, table, grid, cfg.boundary_tile
-        )
+        with stage_scope("bitmask"):
+            masks = backend.bitmasks(proj, table, grid, cfg.boundary_tile)
         # 5) RM FIFO: per-tile compaction by bitmask (linear, order-
         #    preserving). Materialized by the reference backend; virtual
         #    (in-register) for the fused pallas RM, which still reports the
         #    same length/overflow stats.
-        compacted = backend.compact(table, masks, grid, cfg.tile_capacity)
+        with stage_scope("compact"):
+            compacted = backend.compact(table, masks, grid, cfg.tile_capacity)
         # 6) Small-tile rasterization.
-        rast = backend.rasterize_groups(
-            proj,
-            table,
-            masks,
-            compacted,
-            grid,
-            background=background,
-            chunk=cfg.chunk,
-            early_exit=cfg.early_exit,
-            tile_capacity=cfg.tile_capacity,
-        )
+        with stage_scope("raster"):
+            rast = backend.rasterize_groups(
+                proj,
+                table,
+                masks,
+                compacted,
+                grid,
+                background=background,
+                chunk=cfg.chunk,
+                early_exit=cfg.early_exit,
+                tile_capacity=cfg.tile_capacity,
+            )
         stats = RenderStats(
             n_visible=front.n_visible,
             n_candidate_tests=front.n_candidate_tests,
@@ -457,14 +497,15 @@ def _run_backend(
             group=grid.group,
             span=cfg.span,
         )
-    rast = backend.rasterize_tiles(
-        proj,
-        table,
-        raster_grid,
-        background=background,
-        chunk=cfg.chunk,
-        early_exit=cfg.early_exit,
-    )
+    with stage_scope("raster"):
+        rast = backend.rasterize_tiles(
+            proj,
+            table,
+            raster_grid,
+            background=background,
+            chunk=cfg.chunk,
+            early_exit=cfg.early_exit,
+        )
     image = rast.image[: cam.height, : cam.width]
     stats = RenderStats(
         n_visible=front.n_visible,

@@ -76,7 +76,12 @@ from repro.core.pipeline import (
 )
 from repro.core.projection import projected_bytes_per_gaussian
 from repro.launch.mesh import make_render_mesh, render_mesh_shards
-from repro.obs import emit_request_spans, get_registry, get_tracer
+from repro.obs import (
+    emit_request_spans,
+    get_registry,
+    get_tracer,
+    set_annotation_factory,
+)
 from repro.serving.bucketing import BucketingScheduler, padded_size
 from repro.serving.queue import QueueClosed, RequestQueue
 from repro.residency import ResidencyManager
@@ -95,8 +100,36 @@ from repro.sharding.scene import ShardedScene
 from repro.utils import pytree_bytes
 
 _HANDLE_SEQ = itertools.count()
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _FN_CACHE_MAX = 64          # per-handle compiled-renderer bound (mirrors the
                             # legacy global lru maxsize)
+
+
+def _count_compile(event: str, duration_s: float, **_) -> None:
+    if event == COMPILE_EVENT:
+        registry = get_registry()
+        registry.counter("engine.compiles_total").inc()
+        registry.histogram("engine.compile_s").observe(duration_s)
+
+
+def _count_cache_hit(event: str, **_) -> None:
+    if event == CACHE_HIT_EVENT:
+        get_registry().counter("engine.compile_cache_hits_total").inc()
+
+
+def _observe_jax() -> None:
+    """Once per process, at import: live ``repro.obs`` spans open
+    ``jax.profiler`` annotations, and every XLA compile feeds
+    ``engine.compiles_total`` and ``engine.compile_s`` (a load from the
+    persistent compilation cache counts as a compile, and also bumps
+    ``engine.compile_cache_hits_total``)."""
+    set_annotation_factory(jax.profiler.TraceAnnotation)
+    jax.monitoring.register_event_duration_secs_listener(_count_compile)
+    jax.monitoring.register_event_listener(_count_cache_hit)
+
+
+_observe_jax()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -689,7 +722,30 @@ class Renderer:
         twice."""
         self._check_open()
         self._resolve_tile_params(cam)
-        fn = self._fn("single", cam)
+        return self._compiled(
+            self._fn("single", cam),
+            self._scene,
+            jnp.asarray(cam.R), jnp.asarray(cam.t),
+            jnp.float32(cam.fx), jnp.float32(cam.fy),
+            jnp.float32(cam.cx), jnp.float32(cam.cy),
+            _background_array(None),
+        ).memory_analysis()
+
+    def program_text(
+        self,
+        cams: Union[CameraBatch, Sequence[Camera]],
+        pad_to: Optional[int] = None,
+    ) -> str:
+        """The compiled HLO text of the batch program that ``render_batch(
+        cams, pad_to=pad_to)`` runs. Each op's ``op_name`` metadata names
+        its pipeline stage (``core.pipeline.stage_of``), so a device trace
+        can be split by stage. After such a ``render_batch`` this reads the
+        program that ran; nothing compiles twice."""
+        self._check_open()
+        fn, args, _ = self._batch_call(cams, pad_to, None)
+        return self._compiled(fn, *args).as_text()
+
+    def _compiled(self, fn, *args):
         under_mesh = isinstance(fn, functools.partial)
         if under_mesh:
             fn = fn.args[1]                       # unwrap _with_mesh
@@ -697,13 +753,7 @@ class Renderer:
             raise ValueError("timed-stage handles run eagerly: no program")
         with (jax.set_mesh(self._mesh) if under_mesh
               else contextlib.nullcontext()):
-            return fn.lower(
-                self._scene,
-                jnp.asarray(cam.R), jnp.asarray(cam.t),
-                jnp.float32(cam.fx), jnp.float32(cam.fy),
-                jnp.float32(cam.cx), jnp.float32(cam.cy),
-                _background_array(None),
-            ).compile().memory_analysis()
+            return fn.lower(*args).compile()
 
     def render_frontend(self, cam: Camera) -> FrontendResult:
         """Run ONLY the frontend half (project -> identify -> bin -> merge)
@@ -777,6 +827,16 @@ class Renderer:
         a geometry compiles one shape); exactly B images/stats come back.
         """
         self._check_open()
+        fn, args, orig = self._batch_call(cams, pad_to, background)
+        out = fn(*args)
+        if len(args[1]) != orig:              # R, one row per padded lane
+            out = jax.tree.map(lambda x: x[:orig], out)
+        return out
+
+    def _batch_call(self, cams, pad_to, background):
+        """The compiled batch renderer for ``cams`` padded as
+        ``render_batch`` pads them, its arguments, and the unpadded batch
+        size."""
         batch = (
             cams if isinstance(cams, CameraBatch)
             else CameraBatch.from_cameras(cams)
@@ -806,17 +866,14 @@ class Renderer:
         else:
             put_b = lambda a: jax.device_put(a, shard)
             put_bg = lambda a: jax.device_put(a, repl)
-        fn = self._fn("batch", padded)
-        out = fn(
+        args = (
             self._scene,
             put_b(padded.R), put_b(padded.t),
             put_b(padded.fx), put_b(padded.fy),
             put_b(padded.cx), put_b(padded.cy),
             put_bg(_background_array(background)),
         )
-        if len(padded) != orig:
-            out = jax.tree.map(lambda x: x[:orig], out)
-        return out
+        return self._fn("batch", padded), args, orig
 
     # -- futures front-end ---------------------------------------------------
 
@@ -913,12 +970,24 @@ class Renderer:
     def _dispatch_bucket(self, bucket) -> None:
         reqs = bucket.requests
         tracer = get_tracer()
+        lanes = data_extent(self._mesh)
+        padded = padded_size(max(len(reqs), self._max_batch), lanes)
         t0 = self._clock()
         try:
-            out = self.render_batch(
-                [r.camera for r in reqs], pad_to=self._max_batch
-            )
-            host = jax.tree.map(np.asarray, out)   # blocks on device work
+            with tracer.span(
+                "engine/dispatch", category="engine",
+                args={"handle": self.cache_name, "batch_size": len(reqs),
+                      "padded": padded},
+            ):
+                with tracer.span("serve/launch", category="engine"):
+                    out = self.render_batch(
+                        [r.camera for r in reqs], pad_to=self._max_batch
+                    )
+                with tracer.span("serve/device_wait", category="engine"):
+                    out = jax.block_until_ready(out)
+                t_done = self._clock()
+                with tracer.span("serve/fetch", category="engine"):
+                    host = jax.tree.map(np.asarray, out)
             results = [
                 jax.tree.map(lambda x, i=i: x[i], host)
                 for i in range(len(reqs))
@@ -934,8 +1003,6 @@ class Renderer:
                     r.future.set_exception(exc)
             return
         t1 = self._clock()
-        lanes = data_extent(self._mesh)
-        padded = padded_size(max(len(reqs), self._max_batch), lanes)
         with self._worker_lock:
             self._counters["batches"] += 1
             self._counters["completed"] += len(reqs)
@@ -947,17 +1014,12 @@ class Renderer:
         registry.counter("engine.completed_total").inc(len(reqs))
         registry.counter("engine.padded_lanes_total").inc(padded - len(reqs))
         registry.histogram("engine.dispatch_s").observe(t1 - t0)
-        if tracer.enabled:
-            tracer.complete(
-                "engine/dispatch", t0, t1, category="engine",
-                args={"handle": self.cache_name, "batch_size": len(reqs),
-                      "padded": padded},
-            )
         for r, res in zip(reqs, results):
             st = getattr(r, "stamps", None)
             if st is not None:
                 st["dispatch"] = t0
-                st["device_done"] = t1
+                st["device_done"] = t_done
+                st["fetched"] = t1
             if r.future.set_running_or_notify_cancel():
                 r.future.set_result(res)
             if st is not None:

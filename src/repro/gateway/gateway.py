@@ -447,7 +447,8 @@ class RenderGateway:
                 stamps = getattr(req, "stamps", None)
                 if stamps is not None:
                     stamps["dispatch"] = t0
-                    stamps["device_done"] = t1
+                    # The worker copies the images before it returns.
+                    stamps["device_done"] = stamps["fetched"] = t1
             with self._lock:
                 self._inflight[wid] = []
                 self._events.append(("done", wid, batch, out, t0, t1))

@@ -133,6 +133,7 @@ def bitmask_kernel(
         out_specs=pl.BlockSpec((1, 1, block), lambda g, k: (g, 0, k)),
         out_shape=jax.ShapeDtypeStruct((num_groups, 1, K), jnp.int32),
         interpret=interpret,
+        name="gstg_bitmask",
     )
     out = on_every_device(call, interpret)(feat)
     return jax.lax.bitcast_convert_type(out.reshape(num_groups, K),

@@ -276,6 +276,7 @@ def raster_tile_kernel(
         out_specs=pl.BlockSpec((1, OUT_ROWS, PB), lambda t, p: (t, 0, p)),
         out_shape=jax.ShapeDtypeStruct((num_tiles, OUT_ROWS, P), jnp.float32),
         interpret=interpret,
+        name="gstg_raster_tile",
     )
     out = on_every_device(call, interpret)(feat)
     return _split(out, with_stats)
@@ -340,6 +341,7 @@ def raster_group_fused_kernel(
         out_shape=jax.ShapeDtypeStruct(
             (num_groups, tpg, OUT_ROWS, P), jnp.float32),
         interpret=interpret,
+        name="gstg_raster_group",
     )
     out = on_every_device(call, interpret)(
         feat, masks.reshape(num_groups, 1, K))

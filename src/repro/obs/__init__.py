@@ -25,6 +25,7 @@ from repro.obs.trace import (
     emit_request_spans,
     Tracer,
     get_tracer,
+    set_annotation_factory,
     set_tracer,
     span,
     trace_env_enabled,
@@ -46,6 +47,7 @@ __all__ = [
     "emit_request_spans",
     "Tracer",
     "get_tracer",
+    "set_annotation_factory",
     "set_tracer",
     "span",
     "trace_env_enabled",

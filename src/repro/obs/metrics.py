@@ -201,6 +201,13 @@ class MetricsRegistry:
     def histogram(self, name: str, cap: int = DEFAULT_RESERVOIR) -> Histogram:
         return self._get("histogram", name, lambda: Histogram(cap=cap))
 
+    def get(self, name: str):
+        """The instrument registered as ``name``, or None; creates
+        nothing."""
+        with self._lock:
+            entry = self._instruments.get(name)
+        return entry[1] if entry is not None else None
+
     def drop(self, prefix: str) -> int:
         """Remove every instrument whose name starts with ``prefix`` —
         lifecycle hygiene for per-handle gauges (``Renderer.close()``)."""

@@ -20,6 +20,15 @@ requests with (``RequestQueue``/``RenderServer`` defaults), so request
 lifecycle stamps and stage spans line up on one timeline without any
 cross-clock alignment.
 
+Live spans: :meth:`Tracer.span` also opens a profiler annotation through
+the factory a jax-importing layer installs (:func:`set_annotation_factory`;
+``engine/handle.py`` installs ``jax.profiler.TraceAnnotation``), whether or
+not the ring records. The annotation carries the span's start on the
+tracer's clock as its ``mono`` argument, which a profiler trace keeps as a
+stat: every live span is an anchor that places the ring's spans and the
+request stamps on the profiler's clock. With no profiler running an
+annotation costs a few microseconds.
+
 :func:`validate_chrome_trace` is the single schema checker shared by the
 test suite and the CI validator (``scripts/validate_trace.py``): every
 event carries name/ph/ts/dur/pid/tid, and within each (pid, tid) lane the
@@ -34,7 +43,7 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -73,9 +82,10 @@ class SpanEvent:
 class Tracer:
     """Bounded, thread-safe recorder of :class:`SpanEvent`.
 
-    ``enabled`` gates the ambient helpers (:meth:`span`, the decorator,
-    serving lifecycle spans): when off they cost one predicate and record
-    nothing. :meth:`complete` with ``force=True`` records regardless —
+    ``enabled`` gates the ring (:meth:`span`, the decorator, serving
+    lifecycle spans): when off they record nothing, and a live span only
+    opens its profiler annotation. :meth:`complete` with ``force=True``
+    records regardless —
     the timed-stage engine path uses it because ``RenderConfig.timing``
     IS the opt-in there; asking twice would drop spans on the floor.
     """
@@ -147,16 +157,18 @@ class Tracer:
     @contextmanager
     def span(self, name: str, *, category: str = "",
              args: Optional[Dict[str, Any]] = None, tid: Optional[int] = None):
-        """Context manager recording the enclosed wall interval."""
-        if not self._enabled:
-            yield
-            return
+        """Context manager over a live span: the enclosed interval is a
+        profiler annotation anchored by its ``mono`` start (module
+        docstring) and, while the tracer is enabled, a ring record."""
         t0 = self.clock()
-        try:
-            yield
-        finally:
-            self.complete(name, t0, self.clock(), category=category,
-                          args=args, tid=tid)
+        factory = _annotation_factory
+        with factory(name, mono=t0) if factory else nullcontext():
+            try:
+                yield
+            finally:
+                if self._enabled:
+                    self.complete(name, t0, self.clock(), category=category,
+                                  args=args, tid=tid)
 
     # -- introspection --------------------------------------------------------
 
@@ -223,7 +235,8 @@ REQUEST_PHASES = (
     ("enqueue", "batch_form", "request/queue"),
     ("batch_form", "dispatch", "request/batch_wait"),
     ("dispatch", "device_done", "request/device"),
-    ("device_done", "resolve", "request/resolve"),
+    ("device_done", "fetched", "request/fetch"),
+    ("fetched", "resolve", "request/resolve"),
 )
 
 
@@ -311,6 +324,17 @@ def validate_chrome_trace(doc: Any) -> List[str]:
 
 _global_lock = threading.Lock()
 _global: Optional[Tracer] = None
+_annotation_factory: Optional[Callable[..., Any]] = None
+
+
+def set_annotation_factory(
+        factory: Optional[Callable[..., Any]]) -> Optional[Callable[..., Any]]:
+    """Install the profiler annotation every live span opens:
+    ``factory(name, mono=t0)`` returns a context manager. Returns the
+    previous one; None turns annotations off."""
+    global _annotation_factory
+    prev, _annotation_factory = _annotation_factory, factory
+    return prev
 
 
 def get_tracer() -> Tracer:
@@ -350,10 +374,7 @@ def trace_span(name: Optional[str] = None, *, category: str = ""):
 
         @functools.wraps(fn)
         def wrapper(*a, **kw):
-            tracer = get_tracer()
-            if not tracer.enabled:
-                return fn(*a, **kw)
-            with tracer.span(label, category=category):
+            with get_tracer().span(label, category=category):
                 return fn(*a, **kw)
         return wrapper
     return deco

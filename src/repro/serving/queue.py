@@ -52,8 +52,10 @@ class RenderRequest:
     # instead of the stateless batch path. None = stateless request.
     stream_id: Optional[str] = None
     # Lifecycle stamps (DESIGN.md §14): monotonic clock readings keyed
-    # enqueue/batch_form/dispatch/device_done/resolve, written by the queue,
-    # scheduler, and server as the request moves through them. A mutable
+    # enqueue/batch_form/dispatch/device_done/fetched/resolve, written by
+    # the queue, scheduler, and server as the request moves through them
+    # (device_done when the device finished, fetched once the image is on
+    # the host). A mutable
     # dict on a frozen dataclass on purpose — the dict OBJECT survives the
     # ``dataclasses.replace`` copies this request goes through, so every
     # phase writes into one shared map; compare=False keeps it out of the

@@ -36,7 +36,7 @@ import numpy as np
 
 from repro.core.gaussians import GaussianScene
 from repro.core.pipeline import CameraBatch, render_cache_info
-from repro.obs import emit_request_spans, get_tracer
+from repro.obs import emit_request_spans, get_registry, get_tracer
 from repro.residency import ResidencyManager
 from repro.serving.bucketing import Bucket, BucketingScheduler, padded_size
 from repro.serving.queue import RenderRequest, RequestQueue
@@ -311,51 +311,20 @@ class RenderServer:
 
         shape = padded_size(self.scheduler.max_batch, data_extent(self.mesh))
 
-        before = render_cache_info()
-        t0 = self._clock()
-        out = handle.render_batch(batch, pad_to=shape)
-        images = np.asarray(out.image)   # blocks until device work completes
-        t1 = self._clock()
-        after = render_cache_info()
-
         tracer = get_tracer()
-        if tracer.enabled:
-            tracer.complete(
-                "serve/dispatch", t0, t1, category="serving",
-                args={"batch_size": len(reqs), "padded": shape,
-                      "signature": repr(bucket.signature)},
-            )
-        latencies = [t1 - r.enqueue_time for r in reqs]
-        self.stats.record_dispatch(
-            bucket.signature,
-            batch_size=len(reqs),
-            padded_size=shape,
-            render_s=t1 - t0,
-            latencies_s=latencies,
-            cache_before=before,
-            cache_after=after,
-        )
-        for req, img, lat in zip(reqs, images, latencies):
-            missed = req.deadline is not None and t1 > req.deadline
-            if missed:
-                self.stats.count_deadline_miss()
-            self.results[req.request_id] = RequestResult(
-                request_id=req.request_id,
-                image=img,
-                latency_s=lat,
-                batch_size=len(reqs),
-                signature=bucket.signature,
-                deadline_missed=missed,
-            )
-            stamps = getattr(req, "stamps", None)
-            if stamps is not None:
-                stamps["dispatch"] = t0
-                stamps["device_done"] = t1
-                stamps["resolve"] = self._clock()
-                emit_request_spans(
-                    tracer, req.request_id, stamps,
-                    args={"scene_id": req.scene_id},
-                )
+        mark = self._mark()
+        with tracer.span("serve/dispatch", category="serving",
+                         args={"batch_size": len(reqs), "padded": shape,
+                               "signature": repr(bucket.signature)}):
+            with tracer.span("serve/launch", category="serving"):
+                image = handle.render_batch(batch, pad_to=shape).image
+            with tracer.span("serve/device_wait", category="serving"):
+                image.block_until_ready()
+            t_done = self._clock()
+            with tracer.span("serve/fetch", category="serving"):
+                images = np.asarray(image)
+        self._complete(bucket, images, mark, t_done, padded=shape,
+                       span_args={"scene_id": reqs[0].scene_id})
 
     def _dispatch_stream(self, bucket: Bucket) -> None:
         """Dispatch a stream bucket: frames run IN ORDER through the
@@ -368,30 +337,58 @@ class RenderServer:
         reqs = bucket.requests
         stream = self.stream_for(reqs[0])
 
-        before = render_cache_info()
-        t0 = self._clock()
-        images = [np.asarray(stream.render(r.camera).image) for r in reqs]
+        tracer = get_tracer()
+        mark = self._mark()
+        with tracer.span("serve/dispatch", category="serving",
+                         args={"batch_size": len(reqs), "padded": len(reqs),
+                               "stream": stream.name,
+                               "signature": repr(bucket.signature)}):
+            with tracer.span("serve/launch", category="serving"):
+                frames = [stream.render(r.camera).image for r in reqs]
+            with tracer.span("serve/device_wait", category="serving"):
+                for image in frames:
+                    image.block_until_ready()
+            t_done = self._clock()
+            with tracer.span("serve/fetch", category="serving"):
+                images = [np.asarray(image) for image in frames]
+        # Per-frame dispatch: no pad lanes.
+        self._complete(bucket, images, mark, t_done, padded=len(reqs),
+                       span_args={"scene_id": reqs[0].scene_id,
+                                  "stream": stream.name})
+
+    def _mark(self) -> tuple:
+        """What a dispatch's accounting diffs across it: the render-cache
+        table, the process's compile count and seconds, and the launch
+        time."""
+        reg = get_registry()
+        return (render_cache_info(), reg.counter("engine.compiles_total").value,
+                reg.histogram("engine.compile_s").sum, self._clock())
+
+    def _complete(self, bucket: Bucket, images, mark: tuple, t_done: float,
+                  *, padded: int, span_args: dict) -> None:
+        """Fold a finished dispatch into the stats, results and request
+        stamps: launched at ``mark``, device work done at ``t_done``, images
+        on the host now."""
+        reqs = bucket.requests
+        before, compiles, compile_s, t0 = mark
         t1 = self._clock()
         after = render_cache_info()
-
-        tracer = get_tracer()
-        if tracer.enabled:
-            tracer.complete(
-                "serve/dispatch", t0, t1, category="serving",
-                args={"batch_size": len(reqs), "padded": len(reqs),
-                      "stream": stream.name,
-                      "signature": repr(bucket.signature)},
-            )
+        reg = get_registry()
         latencies = [t1 - r.enqueue_time for r in reqs]
         self.stats.record_dispatch(
             bucket.signature,
             batch_size=len(reqs),
-            padded_size=len(reqs),     # per-frame dispatch: no pad lanes
+            padded_size=padded,
             render_s=t1 - t0,
             latencies_s=latencies,
             cache_before=before,
             cache_after=after,
+            queue_waits_s=[t0 - r.enqueue_time for r in reqs],
+            fetch_s=t1 - t_done,
+            compiles=reg.counter("engine.compiles_total").value - compiles,
+            compile_s=reg.histogram("engine.compile_s").sum - compile_s,
         )
+        tracer = get_tracer()
         for req, img, lat in zip(reqs, images, latencies):
             missed = req.deadline is not None and t1 > req.deadline
             if missed:
@@ -407,13 +404,11 @@ class RenderServer:
             stamps = getattr(req, "stamps", None)
             if stamps is not None:
                 stamps["dispatch"] = t0
-                stamps["device_done"] = t1
+                stamps["device_done"] = t_done
+                stamps["fetched"] = t1
                 stamps["resolve"] = self._clock()
-                emit_request_spans(
-                    tracer, req.request_id, stamps,
-                    args={"scene_id": req.scene_id,
-                          "stream": stream.name},
-                )
+                emit_request_spans(tracer, req.request_id, stamps,
+                                   args=span_args)
 
     # -- lifecycle -----------------------------------------------------------
 

@@ -161,7 +161,19 @@ class ServingStats:
         latencies_s: List[float],
         cache_before: Optional[dict] = None,
         cache_after: Optional[dict] = None,
+        queue_waits_s: Optional[List[float]] = None,
+        fetch_s: Optional[float] = None,
+        compiles: Optional[int] = None,
+        compile_s: Optional[float] = None,
     ) -> None:
+        """Fold one dispatch of ``batch_size`` requests. The optional
+        readings publish into the registry in dispatch order, so the last
+        dispatches of a run can be read back: per request
+        ``serving.queue_wait_s`` (dispatch - enqueue) and
+        ``serving.fetch_s`` (image copy to the host, ``fetch_s``); per
+        dispatch ``serving.batch_size``, ``serving.dispatch_compiles`` and
+        ``serving.dispatch_compile_s`` (XLA compiles, or loads from the
+        persistent cache, while the dispatch ran)."""
         delta = None
         if cache_before is not None and cache_after is not None:
             delta = cache_delta(cache_before, cache_after)
@@ -188,6 +200,16 @@ class ServingStats:
         reg.histogram("serving.render_s").observe(render_s)
         lat_h = reg.histogram("serving.latency_s")
         lat_h.observe_many(latencies_s)
+        reg.histogram("serving.batch_size").observe(batch_size)
+        if queue_waits_s is not None:
+            reg.histogram("serving.queue_wait_s").observe_many(queue_waits_s)
+        if fetch_s is not None:
+            reg.histogram("serving.fetch_s").observe_many(
+                [fetch_s] * batch_size)
+        if compiles is not None:
+            reg.histogram("serving.dispatch_compiles").observe(compiles)
+        if compile_s is not None:
+            reg.histogram("serving.dispatch_compile_s").observe(compile_s)
 
     # -- aggregate views ----------------------------------------------------
 

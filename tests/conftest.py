@@ -1,3 +1,5 @@
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -41,6 +43,39 @@ def jit_render(scene, cam, cfg, background=None):
 @pytest.fixture(scope="session")
 def jit_render_fn():
     return jit_render
+
+
+_DEBUG_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                 "StackFrames")
+
+
+def _strip_metadata(hlo_text: str) -> str:
+    lines, skipping = [], False
+    for line in hlo_text.splitlines():
+        if line in _DEBUG_TABLES:
+            skipping = True
+            continue
+        if skipping and line.startswith(("ENTRY", "%", "HloModule")):
+            skipping = False
+        if not skipping:
+            lines.append(line)
+    text = re.sub(r", metadata=\{[^{}]*\}", "", "\n".join(lines))
+    text = re.sub(r'"body":"[^"]*"', '"body":"<kernel>"', text)
+    kernels = re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    for i, name in enumerate(dict.fromkeys(kernels)):
+        text = re.sub(rf"%{re.escape(name)}(?![\w.\-])", f"%kernel_{i}",
+                      text)
+    return text
+
+
+@pytest.fixture(scope="session")
+def strip_metadata():
+    """A compiled program's text without what names and scopes change: op
+    metadata and the debug tables it points into, and each Pallas kernel's
+    instruction name and serialized body (which carries the kernel's name
+    and its ops' source locations)."""
+    return _strip_metadata
 
 
 @pytest.fixture(scope="session")

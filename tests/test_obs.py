@@ -9,6 +9,7 @@ BITWISE-identical images to the default whole-program jit, on both backends.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import subprocess
 import sys
@@ -123,6 +124,36 @@ def test_tracer_disabled_records_nothing_unless_forced():
     assert [e.name for e in tr.events()] == ["forced"]
 
 
+def test_spans_are_live_profiler_annotations_anchored_on_the_clock():
+    """Every span opens the installed annotation with its start on the
+    tracer's clock as ``mono``, recorded in the ring or not."""
+    opened = []
+
+    @contextlib.contextmanager
+    def annotation(name, **args):
+        opened.append((name, args))
+        yield
+
+    prev = obs_trace.set_annotation_factory(annotation)
+    try:
+        clock = _manual_clock(5.0)
+        for enabled in (False, True):
+            tr = Tracer(clock=clock, enabled=enabled)
+            with tr.span("serve/dispatch"):
+                clock.advance(1.0)
+                with tr.span("serve/fetch"):
+                    clock.advance(0.5)
+        assert opened == [("serve/dispatch", {"mono": 5.0}),
+                          ("serve/fetch", {"mono": 6.0}),
+                          ("serve/dispatch", {"mono": 6.5}),
+                          ("serve/fetch", {"mono": 7.5})]
+        assert [e.name for e in tr.events()] == ["serve/fetch",
+                                                 "serve/dispatch"]
+        assert tr.events()[1].t0 == 6.5
+    finally:
+        obs_trace.set_annotation_factory(prev)
+
+
 def test_trace_span_decorator_resolves_tracer_at_call_time():
     from repro.obs import get_tracer, set_tracer
 
@@ -187,12 +218,13 @@ def test_validate_chrome_trace_catches_bad_documents():
 def test_emit_request_spans_tiles_the_lifecycle():
     tr = Tracer(clock=_manual_clock(), enabled=True)
     stamps = {"enqueue": 1.0, "batch_form": 1.2, "dispatch": 1.5,
-              "device_done": 2.5, "resolve": 2.6}
+              "device_done": 2.5, "fetched": 2.55, "resolve": 2.6}
     emit_request_spans(tr, 7, stamps, args={"scene_id": "train"})
     by_name = {e.name: e for e in tr.events()}
     assert set(by_name) == {"request"} | {n for _, _, n in REQUEST_PHASES}
     assert by_name["request"].duration_s == pytest.approx(1.6)
     assert by_name["request/device"].duration_s == pytest.approx(1.0)
+    assert by_name["request/fetch"].duration_s == pytest.approx(0.05)
     assert by_name["request"].args["request_id"] == 7
     # all on one synthetic lane, nested under the enclosing request span
     assert len({e.tid for e in tr.events()}) == 1
@@ -483,3 +515,125 @@ def test_engine_submit_emits_request_spans(small_scene, base_cfg):
         assert validate_chrome_trace(get_tracer().chrome_trace()) == []
     finally:
         set_tracer(prev)
+
+
+def _serve_two_batches(scene, cfg, registry=None):
+    from repro.core import orbit_cameras
+    from repro.serving.queue import RenderRequest
+    from repro.serving.server import RenderServer
+    from repro.serving.stats import ServingStats
+
+    server = RenderServer({"s": scene}, max_batch=2, max_wait=0.0)
+    if registry is not None:
+        server.stats = ServingStats(registry=registry)
+    cams = orbit_cameras(4, 4.5, 64, 48)
+    reqs = [RenderRequest(i, "s", cams[i], cfg) for i in range(4)]
+    for batch in (reqs[:2], reqs[2:]):
+        for r in batch:
+            server.submit(r)
+        server.drain()
+    server.close()
+    return reqs
+
+
+def test_server_dispatch_splits_into_live_phases(small_scene, base_cfg):
+    """Each dispatch records nested serve/launch, serve/device_wait and
+    serve/fetch spans; each request a device_done stamp before the copy
+    and a fetched stamp after it; and the registry the per-request and
+    per-dispatch readings, in dispatch order."""
+    from repro.obs import get_tracer, set_tracer
+
+    reg = MetricsRegistry()
+    prev = set_tracer(Tracer(enabled=True))
+    try:
+        reqs = _serve_two_batches(small_scene, base_cfg, reg)
+        evs = get_tracer().events()
+        assert validate_chrome_trace(get_tracer().chrome_trace()) == []
+    finally:
+        set_tracer(prev)
+    outer = [e for e in evs if e.name == "serve/dispatch"]
+    assert len(outer) == 2
+    for phase in ("serve/launch", "serve/device_wait", "serve/fetch"):
+        spans = [e for e in evs if e.name == phase]
+        assert len(spans) == 2
+        assert all(o.t0 <= s.t0 and s.t1 <= o.t1
+                   for s, o in zip(spans, outer))
+    for r in reqs:
+        st = r.stamps
+        assert (st["enqueue"] <= st["dispatch"] <= st["device_done"]
+                <= st["fetched"] <= st["resolve"])
+    assert {e.name for e in evs if e.category == "request"} == \
+        {"request"} | {n for _, _, n in REQUEST_PHASES}
+    snap = reg.snapshot()["histograms"]
+    assert snap["serving.queue_wait_s"]["count"] == 4
+    assert snap["serving.fetch_s"]["count"] == 4
+    assert reg.get("serving.batch_size").values() == [2.0, 2.0]
+    waits = reg.get("serving.queue_wait_s").values()
+    assert waits[-1] == pytest.approx(
+        reqs[3].stamps["dispatch"] - reqs[3].stamps["enqueue"])
+    fetch = reg.get("serving.fetch_s").values()
+    assert fetch[-1] == pytest.approx(
+        reqs[3].stamps["fetched"] - reqs[3].stamps["device_done"])
+    # A fresh shape compiles in the first dispatch; the second is cached.
+    compiles = reg.get("serving.dispatch_compiles").values()
+    assert compiles[0] >= 1 and compiles[1] == 0
+    assert reg.get("serving.dispatch_compile_s").values()[1] == 0.0
+    assert reg.get("no.such.metric") is None
+
+
+def test_compile_counter_counts_new_shapes_only():
+    import jax
+    import jax.numpy as jnp
+
+    import repro.engine  # noqa: F401 — installs the compile listeners
+    from repro.obs import get_registry
+
+    reg = get_registry()
+    f = jax.jit(lambda x: jnp.cumsum(x) * 3.0)
+
+    def compiles():
+        return reg.counter("engine.compiles_total").value
+
+    a, b, c = (jax.block_until_ready(jnp.full(n, 2.0))
+               for n in (37, 37, 41))
+    n0, s0 = compiles(), reg.histogram("engine.compile_s").sum
+    f(a).block_until_ready()
+    assert compiles() == n0 + 1
+    assert reg.histogram("engine.compile_s").sum > s0
+    f(b).block_until_ready()
+    assert compiles() == n0 + 1
+    f(c).block_until_ready()
+    assert compiles() == n0 + 2
+
+
+def test_live_spans_reach_the_profiler_trace_with_anchors(small_scene,
+                                                          base_cfg,
+                                                          tmp_path):
+    """With the profiler on, the server's live spans are host events of the
+    trace carrying their ``mono`` start: one offset maps them all from the
+    tracer's clock to the trace's."""
+    import warnings
+
+    import jax
+    from jax.profiler import ProfileData
+
+    _serve_two_batches(small_scene, base_cfg)          # compile outside
+    with jax.profiler.trace(str(tmp_path)):
+        _serve_two_batches(small_scene, base_cfg)
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    found = {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serve/"):
+                    with warnings.catch_warnings():
+                        warnings.simplefilter("ignore", DeprecationWarning)
+                        stats = dict(e.stats)
+                    found.setdefault(e.name, []).append(
+                        e.start_ns * 1e-9 - float(stats["mono"]))
+    assert set(found) == {"serve/dispatch", "serve/launch",
+                          "serve/device_wait", "serve/fetch"}
+    assert all(len(v) == 2 for v in found.values())
+    offsets = [o for v in found.values() for o in v]
+    assert max(offsets) - min(offsets) < 1e-3

@@ -137,3 +137,54 @@ def test_data_parallel_batch_runs_kernels_on_own_lanes(v5e_2x2, monkeypatch):
     for line in kernels:
         shape = line.split("= ", 1)[1].split("{", 1)[0]
         assert shape.split("[", 1)[1].startswith("6,"), shape
+
+
+def test_stage_scopes_change_only_metadata_on_the_chip(one_chip, monkeypatch,
+                                                       strip_metadata):
+    """Compiled for the chip, the served batch program is the same with its
+    stage scopes as without them, once metadata and what the kernels' names
+    set are left out; each kernel runs in its own stage's scope under its
+    own name, and every sort in ``bin``."""
+    import contextlib
+    import re
+
+    from repro.core import pipeline
+    from repro.core.gaussians import random_scene
+    from repro.core.pipeline import RenderConfig, stage_of
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    cfg = RenderConfig(mode="gstg", backend="pallas", group_capacity=128,
+                       tile_capacity=128, span=4)
+    scene = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        random_scene(jax.random.key(0), 500))
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def compiled_text():
+        one = pipeline._render_with_traced_camera(cfg, 192, 128, 0.01,
+                                                  100.0)
+        batch = jax.vmap(one, in_axes=(None, 0, 0, 0, 0, 0, 0, None))
+        return jax.jit(batch).lower(
+            scene, spec(2, 3, 3), spec(2, 3), spec(2), spec(2), spec(2),
+            spec(2), spec(3)).compile().as_text()
+
+    text = compiled_text()
+    monkeypatch.setattr(pipeline, "stage_scope",
+                        lambda stage: contextlib.nullcontext())
+    assert strip_metadata(text) == strip_metadata(compiled_text())
+
+    ops = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? (sort|custom-call)\(",
+                     line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if m and (m.group(2) == "sort" or "tpu_custom_call" in line):
+            ops[m.group(1)] = stage_of(op_name.group(1))
+    kernels = {name.split(".")[0]: stage for name, stage in ops.items()
+               if not name.startswith("sort")}
+    assert kernels == {"gstg_bitmask": "bitmask",
+                       "gstg_raster_group": "raster"}
+    sorts = [stage for name, stage in ops.items() if name.startswith("sort")]
+    assert sorts and set(sorts) == {"bin"}
