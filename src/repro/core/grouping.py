@@ -5,9 +5,10 @@ radix sort over duplicated (tileID||depth) keys. XLA needs static shapes, so we
 enumerate a bounded grid of candidate bins per Gaussian (span x span window over
 the bin grid, pre-filtered by the circumscribed-radius bbox exactly like
 GSCore/FlashGS pre-filter with the AABB before running finer tests), flatten
-to a global pair list, and bin with a stable two-key sort (depth, then bin id
-— jnp.lexsort semantics via composed stable argsorts). Per-bin segments are
-then extracted with searchsorted into a fixed-capacity table.
+to a global pair list, and bin with one stable sort keyed on (bin id, depth)
+that carries the Gaussian index along, so the sort itself returns the binned
+indices. Per-bin segments are then extracted with searchsorted into a
+fixed-capacity table.
 
 The SAME machinery runs at group granularity (GS-TG) and tile granularity
 (per-tile baseline): the redundant-sorting reduction the paper measures is the
@@ -211,21 +212,19 @@ def identify(
 def bin_pairs(pairs: PairSet, num_bins: int, capacity: int) -> BinTable:
     """Stable (bin, depth) sort + fixed-capacity segment extraction.
 
-    Stability gives the 3D-GS tie-break (insertion order == gaussian index),
-    which is what makes the GS-TG per-tile subsequence *bitwise* identical to
-    the per-tile baseline ordering.
+    One stable sort keyed on (bin_id, depth) carries gauss_idx as its
+    payload, so it returns the binned Gaussian indices directly: no
+    permutation is materialised or gathered through. Stability breaks
+    (bin, depth) ties by pair-list position, which is the 3D-GS tie-break
+    (insertion order == gaussian index); that is what makes the GS-TG
+    per-tile subsequence *bitwise* identical to the per-tile baseline
+    ordering.
     """
-    # Two-pass stable sort == lexicographic (bin_id, depth, original index).
     # Ordering is non-differentiable by design (3D-GS treats it as constant);
     # stop_gradient also keeps sort JVP machinery out of the backward graph.
-    depth_keys = jax.lax.stop_gradient(pairs.depth)
-    order_d = jnp.argsort(depth_keys, stable=True)
-    bin_by_d = pairs.bin_id[order_d]
-    order_b = jnp.argsort(bin_by_d, stable=True)
-    order = order_d[order_b]
-
-    sorted_bins = pairs.bin_id[order]
-    sorted_gauss = pairs.gauss_idx[order]
+    sorted_bins, _, sorted_gauss = jax.lax.sort(
+        (pairs.bin_id, jax.lax.stop_gradient(pairs.depth), pairs.gauss_idx),
+        num_keys=2, is_stable=True)
 
     starts = jnp.searchsorted(sorted_bins, jnp.arange(num_bins, dtype=jnp.int32))
     ends = jnp.searchsorted(

@@ -3,6 +3,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.grouping import (
     GridSpec,
@@ -90,6 +91,75 @@ def test_grid_spec_validation():
         GridSpec(128, 128, 16, 40)  # group not multiple of tile
     with pytest.raises(ValueError):
         GridSpec(0, 128, 16, 64)    # empty image
+
+
+# ---------------------------------------------------------------------------
+# bin_pairs against a NumPy lexsort oracle
+# ---------------------------------------------------------------------------
+
+
+def _bin_oracle(bin_id, depth, gauss_idx, num_bins, capacity):
+    """BinTable fields from np.lexsort over (bin id, depth, pair position):
+    the lexicographic order the binning sort must reproduce exactly."""
+    order = np.lexsort((np.arange(bin_id.size), depth, bin_id))
+    sorted_bins, sorted_gauss = bin_id[order], gauss_idx[order]
+    lengths = np.bincount(sorted_bins, minlength=num_bins + 1)[:num_bins]
+    starts = np.concatenate([[0], np.cumsum(lengths)[:-1]])
+    k = np.arange(capacity)
+    entry_valid = k[None, :] < np.minimum(lengths, capacity)[:, None]
+    idx = np.minimum(starts[:, None] + k[None, :], bin_id.size - 1)
+    return dict(
+        gauss_idx=np.where(entry_valid, sorted_gauss[idx], 0),
+        entry_valid=entry_valid,
+        lengths=lengths.astype(np.int32),
+        overflow=np.maximum(lengths - capacity, 0).sum(),
+    )
+
+
+def _oracle_pairs(rng, n_pairs, num_bins, depth_pool, invalid_share):
+    """Pair arrays with depths drawn from ``depth_pool`` (heavy ties) and a
+    shuffled Gaussian-index payload, so a tie broken by anything but pair
+    position shows in gauss_idx. Invalid slots carry bin num_bins and +inf."""
+    hit = rng.random(n_pairs) >= invalid_share
+    bin_id = np.where(hit, rng.integers(0, num_bins, n_pairs), num_bins)
+    depth = np.where(hit, rng.choice(np.asarray(depth_pool, np.float32),
+                                     n_pairs), np.inf)
+    return (bin_id.astype(np.int32), depth.astype(np.float32),
+            rng.permutation(n_pairs).astype(np.int32), hit)
+
+
+@pytest.mark.parametrize(
+    "n_pairs,num_bins,capacity,depth_pool,invalid_share,lanes",
+    [
+        (2000, 7, 512, [1.0, 2.0, 3.0], 0.5, 1),      # heavy depth ties
+        (1500, 5, 512, [-0.0, 0.0, 1.0], 0.3, 1),     # -0.0 ties with 0.0
+        (800, 6, 512, [1.0], 1.0, 1),                 # every slot invalid
+        (3000, 4, 16, [0.5, 2.0], 0.2, 1),            # bins past capacity
+        (1200, 9, 32, [-0.0, 0.0, 4.0], 0.6, 2),      # vmapped batch of 2
+    ],
+    ids=["ties", "signed-zero", "all-invalid", "overflow", "vmapped"],
+)
+def test_bin_pairs_matches_lexsort_oracle(n_pairs, num_bins, capacity,
+                                          depth_pool, invalid_share, lanes):
+    """bin_pairs == a NumPy lexsort over (bin id, depth, pair position),
+    field for field: depth ties and -0.0/0.0 break by pair position, +inf
+    invalid slots never enter a bin, and lengths and overflow count a bin's
+    entries past its capacity."""
+    rng = np.random.default_rng(n_pairs + num_bins)
+    cases = [_oracle_pairs(rng, n_pairs, num_bins, depth_pool, invalid_share)
+             for _ in range(lanes)]
+    stack = lambda i: jnp.asarray(np.stack([c[i] for c in cases]))
+    zero = jnp.zeros((lanes,), jnp.int32)
+    pairs = PairSet(bin_id=stack(0), depth=stack(1), gauss_idx=stack(2),
+                    valid=stack(3), n_candidate_tests=zero, n_pairs=zero,
+                    n_span_overflow=zero)
+    tables = jax.jit(jax.vmap(
+        lambda p: bin_pairs(p, num_bins, capacity)))(pairs)
+    for lane, (bin_id, depth, gauss_idx, _) in enumerate(cases):
+        want = _bin_oracle(bin_id, depth, gauss_idx, num_bins, capacity)
+        for field, expected in want.items():
+            got = np.asarray(getattr(tables, field))[lane]
+            np.testing.assert_array_equal(got, expected, err_msg=field)
 
 
 # ---------------------------------------------------------------------------

@@ -144,7 +144,9 @@ def test_stage_scopes_change_only_metadata_on_the_chip(one_chip, monkeypatch,
     """Compiled for the chip, the served batch program is the same with its
     stage scopes as without them, once metadata and what the kernels' names
     set are left out; each kernel runs in its own stage's scope under its
-    own name, and every sort in ``bin``."""
+    own name, and the program's one sort is ``bin``'s: keys (bin id, depth)
+    with the Gaussian index carried as a third operand, so no permutation
+    is built to gather through."""
     import contextlib
     import re
 
@@ -175,16 +177,25 @@ def test_stage_scopes_change_only_metadata_on_the_chip(one_chip, monkeypatch,
                         lambda stage: contextlib.nullcontext())
     assert strip_metadata(text) == strip_metadata(compiled_text())
 
-    ops = {}
+    ops, sort_operands = {}, []
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? (sort|custom-call)\(",
                      line)
         op_name = re.search(r'op_name="([^"]*)"', line)
         if m and (m.group(2) == "sort" or "tpu_custom_call" in line):
             ops[m.group(1)] = stage_of(op_name.group(1))
+        if m and m.group(2) == "sort":
+            types = line.split("= (", 1)[1].split(") sort(", 1)[0]
+            args = line[m.end():].split(")", 1)[0]
+            sort_operands.append((
+                re.findall(r"(?:^|, )([a-z]\w*)\[", types),
+                re.findall(r"%([a-z_\-]+)", args)[3:]))
     kernels = {name.split(".")[0]: stage for name, stage in ops.items()
                if not name.startswith("sort")}
     assert kernels == {"gstg_bitmask": "bitmask",
                        "gstg_raster_group": "raster"}
     sorts = [stage for name, stage in ops.items() if name.startswith("sort")]
-    assert sorts and set(sorts) == {"bin"}
+    assert sorts == ["bin"]
+    # The program's three operands (bin id, depth, Gaussian index), then
+    # the iota the chip's compiler appends to make the sort stable.
+    assert sort_operands == [(["s32", "f32", "s32", "s32"], ["iota"])]
