@@ -6,8 +6,9 @@ obvious thing:
 
 1. Project every Gaussian (EWA splatting): camera-space mean, 2D mean,
    2D covariance with the 0.3 px low-pass on the diagonal, its inverse
-   (the conic), spherical-harmonics colour of degree 0 or 1 along the
-   direction from the camera centre to the mean, and sigmoid opacity. A Gaussian is kept
+   (the conic), spherical-harmonics colour of degree 0 to 3 (the real
+   basis 3D-GS evaluates) along the direction from the camera centre to
+   the mean, and sigmoid opacity. A Gaussian is kept
    when it lies between the near and far planes, its 3-sigma box touches the
    image, its 2D covariance is not degenerate, and its opacity is over
    1/255.
@@ -23,6 +24,12 @@ obvious thing:
        an entry contributes alpha * T * colour while the transmittance T
        before it is above 1e-4; T then becomes T * (1 - alpha).
    The background is black.
+
+A frame whose pairs exceed the pair-list capacity it is given is rendered
+in bands of whole tile rows, each band's pairs within that capacity: every
+Gaussian's box is clipped to the band's rows, so each tile keeps the same
+list in the same order, and the image is bitwise that of one pass (a pass
+with more loop steps only blends further entries of alpha 0).
 
 Because the alpha rule zeroes everything outside q <= 9, any list that
 holds every Gaussian whose q <= 9 region reaches a pixel gives the same
@@ -45,6 +52,12 @@ import numpy as np
 
 SH_C0 = 0.28209479177387814
 SH_C1 = 0.4886025119029199
+SH_C2 = (1.0925484305920792, -1.0925484305920792, 0.31539156525252005,
+         -1.0925484305920792, 0.5462742152960396)
+SH_C3 = (-0.5900435899266435, 2.890611442640554, -0.4570457994644658,
+         0.3731763325901154, -0.4570457994644658, 1.445305721320277,
+         -0.5900435899266435)
+SH_COEFFS = (1, 4, 9, 16)
 COV_BLUR = 0.3
 Q_MAX = 9.0
 ALPHA_MIN = 1.0 / 255.0
@@ -91,23 +104,58 @@ def _rotation(q):
 
 
 def _sh_colour(scene, R, t, passes):
-    """3D-GS view-dependent colour before the +0.5 offset, degree 0 or 1:
-    C0 sh_0 + C1 (-y sh_1 + z sh_2 - x sh_3) with (x, y, z) the unit
+    """3D-GS view-dependent colour before the +0.5 offset, along the unit
     direction from the camera centre -R^T t to the mean."""
     sh = scene["sh"]
-    if sh.shape[1] not in (1, 4):
-        raise ValueError(f"the reference evaluates SH degree 0 or 1, not "
+    if sh.shape[1] not in SH_COEFFS:
+        raise ValueError(f"the reference evaluates SH degree 0 to 3, not "
                          f"{sh.shape[1]} coefficients")
-    rgb = SH_C0 * sh[:, 0, :]
     if sh.shape[1] == 1:
-        return rgb
+        return SH_C0 * sh[:, 0, :]
     centre = -_matmul(R.T, t[:, None], passes)[:, 0]
     d = scene["means3d"] - centre[None, :]
     d = d / (jnp.linalg.norm(d, axis=-1, keepdims=True) + 1e-12)
+    return sh_colour(sh, d, passes)
+
+
+def sh_colour(sh, d, passes=6):
+    """sum_k C_k Y_k(d) sh_k over the (N, K, 3) coefficients ``sh`` and the
+    (N, 3) unit directions d = (x, y, z), as 3D-GS's ``eval_sh``:
+
+    - degree 0: C0 sh_0;
+    - degree 1 adds C1 (-y sh_1 + z sh_2 - x sh_3);
+    - degree 2 adds C2[0] xy sh_4 + C2[1] yz sh_5 + C2[2] (2zz - xx - yy)
+      sh_6 + C2[3] xz sh_7 + C2[4] (xx - yy) sh_8;
+    - degree 3 adds C3[0] y (3xx - yy) sh_9 + C3[1] xyz sh_10 + C3[2]
+      y (4zz - xx - yy) sh_11 + C3[3] z (2zz - 3xx - 3yy) sh_12 + C3[4]
+      x (4zz - xx - yy) sh_13 + C3[5] z (xx - yy) sh_14 + C3[6]
+      x (xx - 3yy) sh_15.
+
+    Every product of direction components and of a basis function with a
+    coefficient goes through ``_mul``; the constants scale in float32.
+    """
+    rgb = SH_C0 * sh[:, 0, :]
+    if sh.shape[1] == 1:
+        return rgb
     m = functools.partial(_mul, passes=passes)
     x, y, z = d[:, 0:1], d[:, 1:2], d[:, 2:3]
-    return rgb + SH_C1 * (m(-y, sh[:, 1, :]) + m(z, sh[:, 2, :])
-                          - m(x, sh[:, 3, :]))
+    rgb = rgb + SH_C1 * (m(-y, sh[:, 1, :]) + m(z, sh[:, 2, :])
+                         - m(x, sh[:, 3, :]))
+    if sh.shape[1] == 4:
+        return rgb
+    xx, yy, zz = m(x, x), m(y, y), m(z, z)
+    xy, yz, xz = m(x, y), m(y, z), m(x, z)
+    basis = [xy, yz, 2.0 * zz - xx - yy, xz, xx - yy]
+    for k, (c, b) in enumerate(zip(SH_C2, basis), start=4):
+        rgb = rgb + c * m(b, sh[:, k, :])
+    if sh.shape[1] == 9:
+        return rgb
+    basis = [m(y, 3.0 * xx - yy), m(xy, z), m(y, 4.0 * zz - xx - yy),
+             m(z, 2.0 * zz - 3.0 * xx - 3.0 * yy), m(x, 4.0 * zz - xx - yy),
+             m(z, xx - yy), m(x, xx - 3.0 * yy)]
+    for k, (c, b) in enumerate(zip(SH_C3, basis), start=9):
+        rgb = rgb + c * m(b, sh[:, k, :])
+    return rgb
 
 
 def project(scene, cam, *, width, height, znear, zfar, passes):
@@ -190,7 +238,11 @@ def _front(scene, cam, *, width, height, znear, zfar, tile, passes):
 
 @functools.partial(jax.jit, static_argnames=(
     "width", "height", "tile", "capacity", "with_counts"))
-def _back(g, box, count, *, width, height, tile, capacity, with_counts):
+def _back(g, box, count, *, width, height, tile, capacity, with_counts,
+          row0=None):
+    """Blends the (width, height) image of the tiles' lists. With ``row0``,
+    the image is a band whose first tile row is the frame's row ``row0``,
+    and ``box`` holds tile rows counted from it."""
     ntx, nty = -(-width // tile), -(-height // tile)
     nt = ntx * nty
     x0, x1, y0, y1 = box
@@ -217,7 +269,8 @@ def _back(g, box, count, *, width, height, tile, capacity, with_counts):
     off = jnp.arange(tile, dtype=jnp.float32) + 0.5
     px = ((tix % ntx) * tile).astype(jnp.float32)[:, None, None] \
         + off[None, None, :]
-    py = ((tix // ntx) * tile).astype(jnp.float32)[:, None, None] \
+    ty = tix // ntx if row0 is None else tix // ntx + row0
+    py = (ty * tile).astype(jnp.float32)[:, None, None] \
         + off[None, :, None]
     px = jnp.broadcast_to(px, (nt, tile, tile)).reshape(nt, tile * tile)
     py = jnp.broadcast_to(py, (nt, tile, tile)).reshape(nt, tile * tile)
@@ -267,11 +320,77 @@ def _capacity(pairs: int, capacity: int | None) -> int:
     return 1 << max(10, math.ceil(math.log2(max(pairs, 1))))
 
 
+@functools.partial(jax.jit, static_argnames=("nty",))
+def _row_pairs(box, count, *, nty):
+    """(Gaussian, tile) pairs in each tile row."""
+    x0, x1, y0, y1 = box
+    w = jnp.where(count > 0, x1 - x0 + 1, 0)
+    diff = jnp.zeros(nty + 1, jnp.int32).at[y0].add(w).at[y1 + 1].add(-w)
+    return jnp.cumsum(diff)[:nty]
+
+
+@jax.jit
+def _band_boxes(box, count, r0, r1):
+    """The boxes clipped to tile rows r0 .. r1 - 1, in rows counted from r0,
+    and the tiles each then covers."""
+    x0, x1, y0, y1 = box
+    y0, y1 = jnp.maximum(y0, r0), jnp.minimum(y1, r1 - 1)
+    count = jnp.where((count > 0) & (y0 <= y1),
+                      (x1 - x0 + 1) * (y1 - y0 + 1), 0)
+    return (x0, x1, y0 - r0, y1 - r0), count
+
+
+def bands(row_pairs, capacity: int) -> list:
+    """Runs ``(r0, r1)`` of whole tile rows, each with at most ``capacity``
+    pairs unless it is a single row that holds more on its own."""
+    out, r0, acc = [], 0, 0
+    for r, n in enumerate(int(n) for n in row_pairs):
+        if r > r0 and acc + n > capacity:
+            out.append((r0, r))
+            r0, acc = r, 0
+        acc += n
+    out.append((r0, len(row_pairs)))
+    return out
+
+
+def _banded(g, box, count, *, width, height, tile, capacity, with_counts):
+    """``_back`` band by band; the image rows of each band and the sums of
+    their counts. A band renders a power-of-two number of tile rows (rows
+    past its own are empty and dropped), so few programs are compiled."""
+    nty = -(-height // tile)
+    rows = np.asarray(_row_pairs(box, count, nty=nty))
+    parts, cnt = [], np.zeros(3)
+    for r0, r1 in bands(rows, capacity):
+        span = min(1 << math.ceil(math.log2(r1 - r0)), nty)
+        bbox, bcount = _band_boxes(box, count, r0, r1)
+        img, c = _back(g, bbox, bcount, width=width, height=span * tile,
+                       tile=tile, with_counts=with_counts,
+                       capacity=_capacity(int(rows[r0:r1].sum()), capacity),
+                       row0=jnp.int32(r0))
+        parts.append(img[:(r1 - r0) * tile])
+        cnt += np.asarray(c, np.float64)
+    return jnp.concatenate(parts, axis=0)[:height], cnt
+
+
 def pose_arrays(pose) -> dict:
     return {"R": jnp.asarray(pose.R, jnp.float32),
             "t": jnp.asarray(pose.t, jnp.float32),
             "fx": jnp.float32(pose.fx), "fy": jnp.float32(pose.fy),
             "cx": jnp.float32(pose.cx), "cy": jnp.float32(pose.cy)}
+
+
+def band_plan(scene, pose, *, capacity, tile=16, passes=6):
+    """``(pairs, row_pairs, bands)`` of ``pose``'s frame: its (Gaussian,
+    tile) pairs, those of each tile row, and the bands ``render`` draws it
+    in with ``capacity`` (one when the pairs fit)."""
+    g, box, count, pairs, _ = _front(
+        scene, pose_arrays(pose), width=pose.width, height=pose.height,
+        znear=pose.znear, zfar=pose.zfar, tile=tile, passes=passes)
+    del g
+    rows = np.asarray(_row_pairs(box, count, nty=-(-pose.height // tile)))
+    pairs = int(pairs)
+    plan = [(0, len(rows))] if pairs <= capacity else bands(rows, capacity)
+    return pairs, rows, plan
 
 
 def render(scene, pose, *, tile=16, passes=6, capacity=None,
@@ -282,15 +401,20 @@ def render(scene, pose, *, tile=16, passes=6, capacity=None,
     tile) pairs of the grown boxes, pairs whose q <= 9 region reaches a
     pixel centre of the tile with alpha >= 1/255 ("touching"), alpha
     evaluations those pairs need at pixels still live, and blends that
-    contributed."""
+    contributed. ``capacity`` is the most pairs one pass lists: a frame
+    with more is rendered in bands of tile rows (``bands``)."""
     kw = dict(width=pose.width, height=pose.height)
     g, box, count, pairs, visible = _front(
         scene, pose_arrays(pose), znear=pose.znear, zfar=pose.zfar,
         tile=tile, passes=passes, **kw)
     pairs = int(pairs)
-    img, cnt = _back(g, box, count, tile=tile,
-                     capacity=_capacity(pairs, capacity),
-                     with_counts=with_counts, **kw)
+    if capacity is None or pairs <= capacity:
+        img, cnt = _back(g, box, count, tile=tile,
+                         capacity=_capacity(pairs, capacity),
+                         with_counts=with_counts, **kw)
+    else:
+        img, cnt = _banded(g, box, count, tile=tile, capacity=capacity,
+                           with_counts=with_counts, **kw)
     if not with_counts:
         return img, None
     cnt = np.asarray(cnt, np.float64)

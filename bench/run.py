@@ -114,15 +114,23 @@ def _peak_bytes(devs):
     return int(max(peaks)) if peaks else None
 
 
-def _program(cell):
-    """The program's objects for this cell: scene wrapper, config, server."""
+def _program(cell, devs):
+    """The program's objects for this cell: scene wrapper, config, server.
+
+    The server's mesh spans exactly ``devs``, the cell's chips, and lays the
+    scene's Gaussians over ``render.scene_shards`` of them (absent: 1, the
+    scene replicated); the requests ask for that layout. Where the shards do
+    not divide the devices, as in a rehearsal on one CPU device, the shard
+    axis is logical."""
     from repro.core.camera import Camera
     from repro.core.gaussians import GaussianScene
     from repro.core.pipeline import RenderConfig
+    from repro.launch.mesh import make_render_mesh, render_mesh_shards
     from repro.serving.queue import RenderRequest
     from repro.serving.server import RenderServer
 
     cfg = RenderConfig(**cell.config["render"])
+    shards = cfg.scene_shards
 
     def make_camera(p):
         return Camera(R=p.R, t=p.t, fx=p.fx, fy=p.fy, cx=p.cx, cy=p.cy,
@@ -131,9 +139,12 @@ def _program(cell):
 
     def open_server(arrays):
         mix = cell.traffic
+        mesh = make_render_mesh(
+            devs, scene_shards=render_mesh_shards(len(devs), shards))
         return RenderServer(
-            {cell.config["name"]: GaussianScene(**arrays)},
-            max_batch=int(mix["max_batch"]), max_wait=float(mix["max_wait"]))
+            {cell.config["name"]: GaussianScene(**arrays)}, mesh=mesh,
+            scene_shards=shards, max_batch=int(mix["max_batch"]),
+            max_wait=float(mix["max_wait"]))
 
     return cfg, make_camera, RenderRequest, open_server
 
@@ -203,7 +214,7 @@ def serve(cell, seed, seconds, *, trace=False, hooks=None) -> Served:
 
     conf, mix = cell.config, cell.traffic
     devs = jax.devices()[:int(cell.entry["chips"])]
-    cfg, make_camera, make_request, open_server = _program(cell)
+    cfg, make_camera, make_request, open_server = _program(cell, devs)
 
     # -- set-up ------------------------------------------------------------
     arrays = make_scene(seed, conf)

@@ -230,7 +230,8 @@ def served_program(server, cell):
     from repro.serving.bucketing import padded_size
     from repro.sharding.policies import data_extent
 
-    cfg, make_camera, _req, _open = _program(cell)
+    cfg, make_camera, _req, _open = _program(cell,
+                                             list(server.mesh.devices.flat))
     conf, mix = cell.config, cell.traffic
     n = int(mix["max_batch"])
     cams = [make_camera(orbit_pose(0.1 * i, orbit_of(cell), conf["width"],

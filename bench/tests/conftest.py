@@ -9,12 +9,9 @@ for p in (ROOT / "src", ROOT):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-import copy  # noqa: E402
-
 import pytest  # noqa: E402
 
-TINY_CELLS = {"tiny.backlog": ("backlog_b2", "train.backlog"),
-              "tiny.viewer": ("viewer", "train.viewer")}
+from bench.tests.cells import with_test_cells  # noqa: E402
 
 
 @pytest.fixture(scope="session")
@@ -26,21 +23,6 @@ def bench_json():
 
 @pytest.fixture
 def tiny_bench(bench_json):
-    """BENCHMARK.json with the test-only tiny configuration (128x96, 3000
-    Gaussians) and two tiny cells that report what the train cells of the
-    same mix report. Not cells of the benchmark: only these tests use
-    them."""
-    b = copy.deepcopy(bench_json)
-    b["configs"].append({"name": "tiny", "source": "test only",
-                         "file": "bench/tests/data/tiny.json",
-                         "reduced": [], "why": "test only"})
-    for name, (traffic, _) in TINY_CELLS.items():
-        b["workloads"].append({"name": name, "config": "tiny",
-                               "traffic": traffic, "chips": 1,
-                               "why": "test only"})
-    for m in b["end_to_end"] + b["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = m["workloads"] + [
-                name for name, (_, like) in TINY_CELLS.items()
-                if like in m["workloads"]]
-    return b
+    """BENCHMARK.json with the test-only configurations and cells of
+    ``bench/tests/cells.py``, added as files and entries alone."""
+    return with_test_cells(bench_json)

@@ -2,11 +2,17 @@
 chip look, the result line, the lower-precision control, and faults
 planted under the timed path that ``correct`` has to catch."""
 import json
+import os
+import subprocess
+import sys
 
+import jax
 import numpy as np
 import pytest
 
 from bench import control, run
+from bench.spec import BENCH
+from bench.tests.rehearse import rehearse
 
 
 def _run(capsys, tiny_bench, workload, seed, *, hooks=None, trace=0,
@@ -154,3 +160,40 @@ def test_table_sizing_reads_lists_within_the_capacities(tiny_bench, capsys):
     assert 0 < last["longest_tile_list"] <= last["longest_group_list"]
     assert last["longest_group_list"] <= last["group_capacity"]
     assert last["tile_lists_exact"] and last["span_overflow"] == 0
+
+
+def test_a_sharded_configuration_is_served_through_a_sharded_commit(
+        tiny_bench):
+    """``render.scene_shards`` 4 in a 4-chip cell: every commit lays the
+    scene over 4 shards, physical where the process has 4 devices and
+    logical (the shard axis over one device) otherwise."""
+    got = rehearse("tiny_sharded.backlog", 2**31 + 99, tiny_bench)
+    assert got["rc"] == 0 and got["correct"] is True, got["check"]
+    physical = len(jax.devices()) >= 4
+    assert got["mesh"]["devices"] == min(4, len(jax.devices()))
+    assert got["handles"]
+    for h in got["handles"]:
+        assert h["scene_shards"] == 4
+        assert h["model"] == (4 if physical else 1)
+
+
+def test_on_four_devices_a_cell_serves_on_its_own_chips():
+    """Four virtual CPU devices: the 1-chip cell's server mesh holds one
+    device, and the 4-chip sharded cell commits a physical 4-way layout
+    whose features are gathered by ``psum``; both runs are correct."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run(
+        [sys.executable, str(BENCH / "tests" / "rehearse.py"),
+         "tiny.backlog", "tiny_sharded.backlog"],
+        env=env, capture_output=True, text=True, timeout=600, check=True)
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    one, four = got["tiny.backlog"], got["tiny_sharded.backlog"]
+    for r in (one, four):
+        assert r["rc"] == 0 and r["correct"] is True, r["check"]
+        assert r["device_count"] == 4 and r["handles"]
+    assert one["mesh"] == {"devices": 1, "shape": {"data": 1}}
+    assert {h["scene_shards"] for h in one["handles"]} == {1}
+    assert four["mesh"] == {"devices": 4, "shape": {"data": 1, "model": 4}}
+    assert all(h == {"scene_shards": 4, "model": 4,
+                     "feature_gather": "psum"} for h in four["handles"])

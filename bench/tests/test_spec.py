@@ -1,8 +1,10 @@
 """BENCHMARK.json against its contract, and discovery of configurations,
 mixes, kinds and metric readers by name."""
+import copy
 import json
 import re
 
+import numpy as np
 import pytest
 
 from bench.spec import BENCH, ROOT, load_cell
@@ -92,6 +94,52 @@ def test_a_new_cell_needs_only_files_and_entries(tiny_bench):
     assert cell.traffic["path"] == "drag"
     assert {m["name"] for m in cell.end_to_end} >= {"frames_per_s",
                                                     "setup_s"}
+
+
+def _scene_shards_within_chips(bench):
+    for w in bench["workloads"]:
+        cell = load_cell(w["name"], bench)
+        shards = cell.config["render"].get("scene_shards", 1)
+        assert shards in (1, w["chips"]), w["name"]
+
+
+def test_scene_shards_are_one_or_the_cells_chips(bench_json, tiny_bench):
+    _scene_shards_within_chips(bench_json)
+    _scene_shards_within_chips(tiny_bench)
+    bad = copy.deepcopy(tiny_bench)
+    for w in bad["workloads"]:
+        if w["name"] == "tiny_sharded.backlog":
+            w["chips"] = 1
+    with pytest.raises(AssertionError, match="tiny_sharded.backlog"):
+        _scene_shards_within_chips(bad)
+
+
+def test_an_sh3_sharded_cell_needs_only_files_and_entries(tiny_bench):
+    """An SH-3, 4-chip, Gaussian-sharded configuration loads by name, its
+    scene and reference are drawn on the CPU, and the 3-pass control
+    differs from the reference at SH degree 3."""
+    from bench import reference
+    from bench.poses import orbit_pose
+    from bench.run import orbit_of
+    from bench.scene import make_scene
+
+    cell = load_cell("tiny_sh3.backlog", tiny_bench)
+    conf = cell.config
+    assert (conf["sh_degree"], cell.entry["chips"]) == (3, 4)
+    assert conf["render"]["scene_shards"] == 4
+    scene = make_scene(2**31 + 5, conf)
+    assert scene["sh"].shape == (conf["gaussians"], 16, 3)
+    pose = orbit_pose(1.0, orbit_of(cell), conf["width"], conf["height"])
+    img, counts = reference.render(
+        scene, pose, capacity=conf["correct"]["pair_capacity"],
+        with_counts=True)
+    img = np.asarray(img)
+    assert img.shape == (conf["height"], conf["width"], 3)
+    assert np.isfinite(img).all() and img.max() > 0.0
+    assert counts["sh_coeffs"] == 16 and counts["visible"] > 0
+    low, _ = reference.render(
+        scene, pose, capacity=conf["correct"]["pair_capacity"], passes=3)
+    assert not np.array_equal(np.asarray(low), img)
 
 
 def test_readers_return_nothing_without_work_counts():

@@ -99,3 +99,21 @@ def test_least_time_names_the_bound():
     assert work.least_time(1000.0, 50.0, peak) == (10.0, "compute")
     t, bound = work.least_time(100.0, 50.0, peak)
     assert (t, bound) == (pytest.approx(5.0), "memory")
+
+
+@pytest.mark.parametrize("n_sh, colour_ops", [(1, 12), (4, 46), (9, 91),
+                                              (16, 163)])
+def test_frame_work_counts_the_colour_of_every_sh_degree(n_sh, colour_ops):
+    counts = {"gaussians": 5, "sh_coeffs": n_sh, "touch_pairs": 7,
+              "alpha_evals": 1000, "blends": 300}
+    w = work.frame_work(counts, W, H)
+    assert work.COLOUR_OPS[n_sh] == colour_ops
+    assert w["frame_ops"] - w["kernel_ops"] == 5 * (275 + colour_ops)
+    assert w["frame_bytes"] - w["kernel_bytes"] == 5 * (44 + n_sh * 12)
+
+
+def test_frame_work_refuses_a_coefficient_count_of_no_degree():
+    counts = {"gaussians": 5, "sh_coeffs": 2, "touch_pairs": 7,
+              "alpha_evals": 1000, "blends": 300}
+    with pytest.raises(ValueError, match="for 2 SH coefficients"):
+        work.frame_work(counts, W, H)
