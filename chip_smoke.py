@@ -18,9 +18,15 @@ One chip:
                  their CPU reference images (catches bfloat16 matmul passes).
 
 Four chips (``--four-chips``, nothing else runs): rubble at native
-4608x3456 with ``scene_shards=4`` on a (data=1, model=4) mesh against the
-replicated one-chip render of the same cameras, at the Gaussian count that
-replicated render fits (``SERVE_SPECS``).
+4608x3456 and SH degree 3 with ``scene_shards=4`` on a (data=1, model=4)
+mesh, the backend split by group band. Every frame is lossless and finite,
+and its band entries add up to its bitmask tests.
+  (1) a quarter of the paper's Gaussians (2,265,000), the most whose
+      replicated one-chip render fits, against that render of the same
+      cameras: images within ``SHARDED_MAX_ABS``, frontend counters equal;
+  (2) the paper size (9,060,000 Gaussians). No one-chip render of it fits;
+      the benchmark's cell ``rubble.paper_4chip`` checks it against the
+      plain reference (``bench/check.py``).
 
 Times printed on the way are set-up and information, not benchmark numbers.
 The last line of stdout is ``{"ok": true, "device": {...}}``; any failure
@@ -57,8 +63,13 @@ COUNTER_REL = 1e-5
 # to a few ulp; bfloat16 passes shift splat centres by ~0.1 px and images
 # by ~1e-2.
 GOLDEN_MAX_ABS = 1e-3
-# Four chips: the sharded render against the replicated one.
+# Four chips (1): the split render against the replicated one, and the
+# table sizes of its quarter-size scene: the paper size's longest lists,
+# 49k (group) and 13.7k (tile) at 1/64 of the Gaussians scaled by 64, over
+# four, with room.
 SHARDED_MAX_ABS = 1e-5
+QUARTER_GAUSSIANS = 2_265_000
+QUARTER_CAPACITY = (16384, 6144)
 
 
 class SmokeFailure(RuntimeError):
@@ -290,8 +301,72 @@ def _plan(handle, cam):
             f"outputs={m.output_size_in_bytes} temp={m.temp_size_in_bytes}")
 
 
+def _render_all(jax, handle, cams):
+    """Each camera's output on the host, with its stats as floats."""
+    import numpy as np
+
+    outs = []
+    for cam in cams:
+        out = jax.tree.map(np.asarray, handle.render(cam))
+        outs.append((out, {k: float(v) for k, v in vars(out.stats).items()}))
+    return outs
+
+
+def _split_render(jax, devs, scene, cfg, cams, label):
+    """``cams`` rendered with ``scene`` Gaussian-sharded over ``devs``, the
+    backend split by group band; each frame checked lossless, finite and
+    with band entries that add up to its bitmask tests."""
+    import numpy as np
+
+    from repro import engine
+    from repro.launch.mesh import make_render_mesh
+
+    scene_bytes = sum(x.nbytes for x in jax.tree.leaves(scene))
+    mesh4 = make_render_mesh(devs, scene_shards=4)
+    print(f"[4] {label}: {scene.num_gaussians} Gaussians, SH degree "
+          f"{scene.sh_degree}, {len(cams)} cameras; group_capacity="
+          f"{cfg.group_capacity} tile_capacity={cfg.tile_capacity} "
+          f"span={cfg.span}; mesh {dict(mesh4.shape)}")
+    t0 = time.perf_counter()
+    with engine.open(scene, cfg, mesh=mesh4) as h:
+        st = h.stats()
+        check(st["physical_shards"] == 4,
+              f"scene is not physically 4-way sharded: {st}")
+        print(f"[4] commit: physical_shards={st['physical_shards']} "
+              f"scene_mb_per_device={st['scene_mb_per_device']:.1f} "
+              f"feature_mb_per_device={st['feature_mb_per_device']:.1f}")
+        for d in devs:
+            held = sum(s.data.nbytes for leaf in jax.tree.leaves(
+                h.committed_scene) for s in leaf.addressable_shards
+                if s.device == d)
+            print(f"[4] {d}: committed scene bytes {held} "
+                  f"(whole scene {scene_bytes})")
+        outs = _render_all(jax, h, cams)
+        print(f"[4] split renders {time.perf_counter() - t0:.1f}s "
+              "(set-up, incl. compile)")
+        for i, (out, stats) in enumerate(outs):
+            print(f"[4] camera {i}: band entries {out.bands.tolist()} "
+                  f"overflow={stats['overflow']:.0f} "
+                  f"span_overflow={stats['span_overflow']:.0f} "
+                  f"visible={stats['n_visible']:.0f}")
+            check(np.isfinite(out.image).all(),
+                  f"{label} camera {i}: non-finite pixels")
+            check(stats["overflow"] == 0 and stats["span_overflow"] == 0,
+                  f"{label} camera {i}: not lossless")
+            check(out.bands.shape == (4,)
+                  and out.bands.sum() * 16 == stats["n_bit_tests"],
+                  f"{label} camera {i}: band entries {out.bands} do not "
+                  "add up")
+        print(f"[4] split program, per device: {_plan(h, cams[0])}")
+        for d in devs:
+            print(f"[4] {d} after the split renders: {_device_bytes(d)}")
+    return outs
+
+
 def phase_four_chips(jax, args):
-    """Rubble gaussian-sharded over four chips vs replicated on one."""
+    """Rubble Gaussian-sharded over four chips, the backend split by group
+    band: at a quarter of its Gaussians against the replicated one-chip
+    render, then at its paper size."""
     import numpy as np
 
     from repro import engine
@@ -304,79 +379,45 @@ def phase_four_chips(jax, args):
     devs = jax.devices()
     check(len(devs) == 4, f"--four-chips needs 4 devices, found {len(devs)}")
     spec, serve = PAPER_SCENES["rubble"], SERVE_SPECS["rubble"]
-    scene = scene_like_paper(jax.random.key(args.seed), "rubble",
-                             serve.gaussians)
     cams = orbit_cameras(args.four_chip_cameras,
                          serve.orbit_radius * spec.extent,
                          spec.width, spec.height)
-    cfg = RenderConfig(
-        mode="gstg", tile=16, group=64, backend="pallas",
-        group_capacity=serve.group_capacity,
-        tile_capacity=serve.tile_capacity, span=serve.span,
-    )
-    print(f"[4] scene rubble: {scene.num_gaussians} Gaussians (paper size "
-          f"{spec.paper_gaussians}), "
-          f"{spec.width}x{spec.height}, {len(cams)} cameras; "
-          f"group_capacity={cfg.group_capacity} "
-          f"tile_capacity={cfg.tile_capacity} span={cfg.span}")
 
-    def render_all(handle):
-        outs = []
-        for cam in cams:
-            out = handle.render(cam)
-            outs.append((np.asarray(out.image),
-                         {k: float(np.asarray(v))
-                          for k, v in vars(out.stats).items()}))
-        return outs
+    def config(capacity, shards):
+        return RenderConfig(
+            mode="gstg", tile=16, group=64, backend="pallas",
+            group_capacity=capacity[0], tile_capacity=capacity[1],
+            span=serve.span, scene_shards=shards)
 
-    scene_bytes = sum(x.nbytes for x in jax.tree.leaves(scene))
-    mesh4 = make_render_mesh(devs, scene_shards=4)
-    print(f"[4] sharded mesh: {dict(mesh4.shape)}")
+    # (1) a quarter of the Gaussians: split against replicated on one chip.
+    key = jax.random.key(args.seed)
+    scene = scene_like_paper(key, "rubble", QUARTER_GAUSSIANS, sh_degree=3)
+    split = _split_render(jax, devs, scene, config(QUARTER_CAPACITY, 4),
+                          cams, "quarter")
     t0 = time.perf_counter()
-    with engine.open(scene, dataclasses.replace(cfg, scene_shards=4),
-                     mesh=mesh4) as h:
-        st = h.stats()
-        check(st["physical_shards"] == 4,
-              f"scene is not physically 4-way sharded: {st}")
-        print(f"[4] commit: physical_shards={st['physical_shards']} "
-              f"feature_gather={st['feature_gather']} "
-              f"scene_mb_per_device={st['scene_mb_per_device']:.1f}")
-        for d in devs:
-            held = sum(s.data.nbytes for leaf in jax.tree.leaves(
-                h.committed_scene) for s in leaf.addressable_shards
-                if s.device == d)
-            print(f"[4] {d}: committed scene bytes {held} "
-                  f"(whole scene {scene_bytes})")
-        sharded = render_all(h)
-        print(f"[4] sharded renders {time.perf_counter() - t0:.1f}s "
-              "(set-up, incl. compile)")
-        print(f"[4] sharded program, per device: {_plan(h, cams[0])}")
-        for d in devs:
-            print(f"[4] {d} after the sharded renders: {_device_bytes(d)}")
-
-    t0 = time.perf_counter()
-    with engine.open(scene, cfg, mesh=make_render_mesh(devs[:1])) as h:
-        replicated = render_all(h)
+    with engine.open(scene, config(QUARTER_CAPACITY, 1),
+                     mesh=make_render_mesh(devs[:1])) as h:
+        replicated = _render_all(jax, h, cams)
         print(f"[4] one-chip replicated renders "
               f"{time.perf_counter() - t0:.1f}s (set-up, incl. compile)")
         print(f"[4] replicated program: {_plan(h, cams[0])}")
         print(f"[4] {devs[0]} after the replicated renders: "
               f"{_device_bytes(devs[0])}")
-
-    for i, ((img_s, st_s), (img_r, st_r)) in enumerate(
-            zip(sharded, replicated)):
-        diff = float(np.abs(img_s - img_r).max())
-        print(f"[4] camera {i}: max|d| sharded vs replicated {diff:.3e} "
-              f"overflow={st_s['overflow']:.0f} "
-              f"span_overflow={st_s['span_overflow']:.0f} "
-              f"visible={st_s['n_visible']:.0f}")
-        check(np.isfinite(img_s).all(), f"camera {i}: non-finite pixels")
+    for i, ((out_s, st_s), (out_r, st_r)) in enumerate(
+            zip(split, replicated)):
+        diff = float(np.abs(out_s.image - out_r.image).max())
+        print(f"[4] camera {i}: max|d| split vs replicated {diff:.3e}")
         check(diff <= SHARDED_MAX_ABS,
-              f"camera {i}: sharded vs replicated {diff} > {SHARDED_MAX_ABS}")
-        check(st_s["overflow"] == 0 and st_s["span_overflow"] == 0,
-              f"camera {i}: not lossless")
+              f"camera {i}: split vs replicated {diff} > {SHARDED_MAX_ABS}")
         for k in ("n_visible", "n_pairs_sort", "tile_entries"):
             check(st_s[k] == st_r[k], f"camera {i}: {k} {st_s[k]} != {st_r[k]}")
+    del scene, split, replicated
+
+    # (2) the paper size.
+    scene = scene_like_paper(key, "rubble", serve.gaussians, sh_degree=3)
+    _split_render(jax, devs, scene,
+                  config((serve.group_capacity, serve.tile_capacity), 4),
+                  cams, f"paper size ({spec.paper_gaussians})")
 
 
 def main(argv=None) -> int:

@@ -11,7 +11,8 @@ Cross-checks the three observability surfaces one ``repro.launch
   * stage coverage: with REPRO_TRACE=1 the timed renders must record >= 7
     distinct ``cat == "stage"`` span names (project/identify/bin/bitmask/
     compact/rasterize + the enclosing render; merge rides along when the
-    scene is gaussian-sharded);
+    scene is gaussian-sharded; the group-split backend's ``exchange`` is a
+    scope of the fused program, which timed renders do not run);
   * trace <-> metrics <-> summary consistency: completed requests and
     dispatched batches must agree between the request/serve spans, the
     ``serving.*`` counters + latency histogram, and the stats summary
@@ -19,6 +20,9 @@ Cross-checks the three observability surfaces one ``repro.launch
     nested ``serve/launch``, ``serve/device_wait`` and ``serve/fetch``
     spans, and every request its ``request/device`` and ``request/fetch``
     phases;
+  * band split (DESIGN.md §10): ``serving.band_skew``, one per request a
+    group-split backend drew, is at least 1 and counts no more requests
+    than completed;
   * residency paging (DESIGN.md §17): ``residency/page_in|page_out``
     span counts must equal the ``residency.page_ins_total|page_outs_total``
     counters (span + counter are recorded in the same critical section),
@@ -235,6 +239,17 @@ def validate(trace_doc: dict, metrics_doc: dict) -> list:
         if missing:
             errs.append(f"{len(missing)} request(s) have no {phase} span: "
                         f"{sorted(missing)[:5]}")
+
+    # Band split (DESIGN.md §10): each request a group-split backend drew
+    # publishes one serving.band_skew, its largest band's entries over the
+    # mean band's: never below 1, and for no more requests than completed.
+    skew = hists.get("serving.band_skew")
+    if skew and skew.get("count"):
+        if skew["count"] > (completed or 0):
+            errs.append(f"serving.band_skew count = {skew['count']} but "
+                        f"summary.completed = {completed}")
+        if skew.get("min", 1.0) < 1.0:
+            errs.append(f"serving.band_skew min = {skew['min']} < 1")
 
     errs.extend(validate_residency(xs, counters))
     return errs

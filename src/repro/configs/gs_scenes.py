@@ -75,12 +75,9 @@ SERVE_SPECS: Dict[str, ServeSpec] = {
     # list 14.6k and longest tile list 3.9k once scaled by 16.
     "train": ServeSpec(1_026_000, 3.0, span=6, group_capacity=24576,
                        tile_capacity=8192),
-    # A quarter of the paper size, at native resolution. At 9.06M Gaussians
-    # the longest group list (~49k) pads every one of the 3888 groups'
-    # kernel feature blocks to 14 GB, past one chip's 16 GB, and the
-    # replicated one-chip render it is checked against cannot run. 1/64 of
-    # the Gaussians at 4 extents: span 4, longest lists 49k (group) and
-    # 13.7k (tile) once scaled by 64.
-    "rubble": ServeSpec(2_265_000, 4.0, span=6, group_capacity=16384,
-                        tile_capacity=6144),
+    # Paper size, served Gaussian-sharded on four chips with the backend
+    # split by group band (bench/configs/rubble.json, whose table sizes
+    # these are).
+    "rubble": ServeSpec(9_060_000, 4.0, span=6, group_capacity=66560,
+                        tile_capacity=18432),
 }

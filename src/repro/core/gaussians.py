@@ -19,6 +19,9 @@ import numpy as np
 from repro.utils import HIGHEST
 
 SH_C0 = 0.28209479177387814  # Y_0^0
+# Coefficients per colour channel of SH degrees 0-3, (degree + 1)^2.
+SH_COEFFS = (1, 4, 9, 16)
+
 
 
 @jax.tree_util.register_dataclass
@@ -91,7 +94,11 @@ def random_scene(
 ) -> GaussianScene:
     """Synthetic scene with clustered Gaussians (mimics real-scene tile-sharing
     statistics better than uniform: real 3D-GS scenes are strongly clustered).
+    ``sh_degree`` 0-3 draws (degree + 1)^2 coefficients per channel: the
+    degree-0 term from a uniform colour, the others 0.1 times a normal.
     """
+    if not 0 <= sh_degree <= 3:
+        raise ValueError(f"sh_degree must be 0-3, got {sh_degree}")
     k1, k2, k3, k4, k5, k6, k7 = jax.random.split(key, 7)
     if cluster:
         n_clusters = max(1, num_gaussians // 64)
@@ -130,7 +137,9 @@ def random_scene(
     )
 
 
-def scene_like_paper(key: jax.Array, name: str, num_gaussians: Optional[int] = None) -> GaussianScene:
+def scene_like_paper(key: jax.Array, name: str,
+                     num_gaussians: Optional[int] = None,
+                     sh_degree: int = 0) -> GaussianScene:
     """Synthetic stand-in scaled to the paper's six evaluation scenes.
 
     Pretrained 3D-GS-30k checkpoints are not shipped offline; these scenes match
@@ -146,5 +155,6 @@ def scene_like_paper(key: jax.Array, name: str, num_gaussians: Optional[int] = N
         key,
         n,
         extent=spec.extent,
+        sh_degree=sh_degree,
         cluster=True,
     )

@@ -44,7 +44,9 @@ bitwise-identical to the legacy flat concat), so per-camera activation
 bytes scale 1/D alongside the persistent parameters. The engine handle
 commits the strategy (engine/handle.py); ``serving/sharded.py`` lays the
 shard axis over a 2-D (data=cameras, model=gaussians) mesh for scenes too
-large to replicate.
+large to replicate. There the gstg Pallas backend is split too
+(``model_bands``): each chip merges, bitmasks and rasterizes one band of
+the groups, after an all-to-all of the per-shard bin tables.
 
 Losslessness guarantees (tested in tests/test_pipeline_lossless.py):
   * BITWISE image equality gstg == tile_baseline whenever the bitmask method
@@ -74,21 +76,23 @@ import numpy as np
 
 from repro.core.camera import Camera
 from repro.core.gaussians import GaussianScene
-from repro.core.grouping import GridSpec, sort_op_count
+from repro.core.grouping import BinTable, GridSpec, sort_op_count
 from repro.core.projection import (
     FEATURE_GATHER_STRATEGIES,
+    Projected,
     ShardedProjected,
     proj_valid_count,
 )
 from repro.core.stages import (
     Backend,
+    PallasBackend,
     TimedBackend,
     get_backend,
     timed_stage_cache_clear,
     timed_stage_cache_info,
 )
 from repro.sharding.scene import SceneLike, ShardedScene, shard_scene
-from repro.utils import wide_count_dtype, wide_count_sum
+from repro.utils import cdiv, wide_count_dtype, wide_count_sum
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,6 +147,9 @@ class RenderStats:
 class RenderResult:
     image: jnp.ndarray
     stats: RenderStats
+    # Valid group-table entries of each band of a group-split backend
+    # ((bands,) int32, band order); None where one backend drew every group.
+    bands: Optional[jnp.ndarray] = None
 
 
 @jax.tree_util.register_dataclass
@@ -172,8 +179,8 @@ class FrontendResult:
 # its ``op_name`` metadata and a device trace can be split by stage. Scopes
 # change metadata only: the compiled program is otherwise the same.
 STAGE_SCOPE = "gstg"
-STAGES = ("project", "identify", "bin", "merge", "bitmask", "compact",
-          "raster")
+STAGES = ("project", "identify", "bin", "exchange", "merge", "bitmask",
+          "compact", "raster")
 
 
 def stage_scope(stage: str):
@@ -307,7 +314,8 @@ def _frontend(
             tables_s = backend.bin_shards(pairs_s, num_bins, capacity)
     else:
         with stage_scope("project"):
-            proj_s = jax.vmap(lambda s: backend.project(s, cam))(scene.shards)
+            proj_s = jax.vmap(lambda s: _project_in_chunks(backend, s, cam))(
+                scene.shards)
         with stage_scope("identify"):
             pairs_s = jax.vmap(
                 lambda p: backend.identify(p, grid, level, method)
@@ -346,6 +354,213 @@ def _frontend(
         jnp.sum(pairs_s.n_pairs),
         jnp.sum(pairs_s.n_span_overflow),
     )
+
+
+def splits_backend(cfg: RenderConfig) -> bool:
+    """Whether ``cfg`` renders with a backend that splits by group band on
+    a 'model' axis (``model_bands``): gstg on the Pallas backend, with the
+    features kept per shard (any gather but ``'flat'``)."""
+    return (cfg.mode == "gstg"
+            and isinstance(get_backend(cfg.backend), PallasBackend)
+            and resolve_feature_gather(cfg) != "flat")
+
+
+def model_bands(cfg: RenderConfig, backend: Backend, num_shards: int) -> int:
+    """How many group bands a gstg render's backend is split into.
+
+    The extent of the context mesh's 'model' axis when the scene's
+    ``num_shards`` shards lie over it, one per chip (engine/handle.py
+    commits that layout), the Pallas backend draws, and the features stay
+    per shard (not ``'flat'``): each chip then merges, bitmasks, packs and
+    rasterizes its own band of the groups (``_frontend_bands``,
+    ``_backend_bands``; DESIGN.md §10). Otherwise 1: one backend draws
+    every group, replicated on every chip of the mesh.
+    """
+    if (num_shards <= 1 or not isinstance(backend, PallasBackend)
+            or not splits_backend(cfg)):
+        return 1
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:
+        return 1
+    return num_shards if dict(mesh.shape).get("model") == num_shards else 1
+
+
+# Most Gaussians of a scene shard projected at once. The projection's
+# batched 3x3 products lie with 3x3 in the minor (4, 128) tile, 57x their
+# bytes: 4.6 GB an array at a quarter of paper-size rubble.
+PROJECT_ROWS = 1 << 20
+
+
+def _project_in_chunks(backend: Backend, scene: GaussianScene,
+                       cam) -> Projected:
+    """``backend.project`` over at most ``PROJECT_ROWS`` Gaussians at a
+    time (``lax.map``); each Gaussian's features are computed as in one
+    pass."""
+    n = scene.num_gaussians
+    k = cdiv(n, PROJECT_ROWS)
+    if k == 1:
+        return backend.project(scene, cam)
+    rows = cdiv(n, k)
+    pad = k * rows - n
+    chunks = jax.tree.map(
+        lambda x: jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        .reshape((k, rows) + x.shape[1:]), scene)
+    proj = jax.lax.map(lambda s: backend.project(s, cam), chunks)
+    return jax.tree.map(lambda x: x.reshape((k * rows,) + x.shape[2:])[:n],
+                        proj)
+
+
+def _frontend_bands(
+    backend: Backend,
+    scene: ShardedScene,
+    cam,
+    grid: GridSpec,
+    level: str,
+    method: str,
+    capacity: int,
+    bands: int,
+    feature_gather: str,
+):
+    """``_frontend`` of a scene whose shards lie one per chip over the
+    'model' axis, for a group-split backend.
+
+    Each chip projects, identifies and bins its own shard into all G
+    groups, as ``_frontend`` does. One all-to-all (the ``exchange`` stage)
+    then hands chip d band d of every shard's table, shard-major: groups
+    ``[d * Gb, (d + 1) * Gb)``, Gb = ceil(G / bands), the last band padded
+    with empty groups. Each chip merges its band (``merge_bin_tables``, as
+    it stands). The returned table is the merged table of ``_frontend``
+    with its groups laid over 'model' and padded to ``bands * Gb`` rows;
+    its ``lengths`` and ``overflow`` are whole and replicated.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    num_bins = grid.num_groups
+    band = cdiv(num_bins, bands)
+    pad = band * bands - num_bins
+    shard_size = scene.shard_size
+
+    def body(shards, R, t, fx, fy, cx, cy):
+        # This chip's one shard, without the leading shard axis: a vmap
+        # over it would give every gather of the frontend one more index.
+        shard = jax.tree.map(lambda x: x[0], shards)
+        c = Camera(R=R, t=t, fx=fx, fy=fy, cx=cx, cy=cy, width=cam.width,
+                   height=cam.height, znear=cam.znear, zfar=cam.zfar)
+        with stage_scope("project"):
+            proj = _project_in_chunks(backend, shard, c)
+        with stage_scope("identify"):
+            pairs = backend.identify(proj, grid, level, method)
+        with stage_scope("bin"):
+            table = backend.bin(pairs, num_bins, capacity)
+        with stage_scope("exchange"):
+            # Global indices and merge keys, gathered on the shard's own
+            # chip (as in _frontend), then this chip's band of every
+            # shard's table, shard-major.
+            valid = table.entry_valid
+            offset = jax.lax.axis_index("model") * shard_size
+            gauss_idx = jnp.where(valid, table.gauss_idx + offset, 0)
+            depth = jnp.where(valid, proj.depth[table.gauss_idx], jnp.inf)
+
+            def swap(a):
+                widths = ((0, pad),) + ((0, 0),) * (a.ndim - 1)
+                return jax.lax.all_to_all(jnp.pad(a, widths)[None], "model",
+                                          1, 0, tiled=True)
+
+            gauss_idx, depth, lengths = (
+                swap(gauss_idx), swap(depth), swap(table.lengths))
+        with stage_scope("merge"):
+            # Each shard's entries are its first min(length, K) slots.
+            k = jnp.arange(capacity, dtype=jnp.int32)
+            valid = k < jnp.minimum(lengths, capacity)[..., None]
+            merged = backend.merge(
+                BinTable(gauss_idx=gauss_idx, entry_valid=valid,
+                         lengths=lengths,
+                         overflow=jnp.zeros((), jnp.int32)),
+                depth)
+            lengths = jax.lax.all_gather(merged.lengths, "model", tiled=True)
+            overflow = jax.lax.psum(merged.overflow, "model")
+        counts = (pairs.n_candidate_tests, pairs.n_pairs,
+                  pairs.n_span_overflow)
+        return (jax.tree.map(lambda x: x[None], proj), merged.gauss_idx,
+                merged.entry_valid, lengths, overflow,
+                tuple(x[None] for x in counts))
+
+    model, repl = P("model"), P()
+    proj_s, gauss_idx, valid, lengths, overflow, counts = jax.shard_map(
+        body,
+        in_specs=(model,) + (repl,) * 6,
+        out_specs=(model, model, model, repl, repl, model),
+        check_vma=False,
+    )(scene.shards, cam.R, cam.t, jnp.asarray(cam.fx, jnp.float32),
+      jnp.asarray(cam.fy, jnp.float32), jnp.asarray(cam.cx, jnp.float32),
+      jnp.asarray(cam.cy, jnp.float32))
+    table = BinTable(gauss_idx=gauss_idx, entry_valid=valid,
+                     lengths=lengths, overflow=overflow)
+    proj = ShardedProjected(shards=proj_s, gather=feature_gather)
+    return proj, table, tuple(jnp.sum(c) for c in counts)
+
+
+def _backend_bands(backend: PallasBackend, front: FrontendResult, grid,
+                   cfg: RenderConfig, background, bands: int):
+    """Stages 4-6 of gstg split by group band over the 'model' axis.
+
+    Chip d takes band d of ``front.table`` (``_frontend_bands``) and every
+    Gaussian's kernel fields, all-gathered from the chips that projected
+    them (the ``exchange`` stage: ``kernels/layout.PACKED_FIELDS``, 60
+    bytes a Gaussian). It bitmasks, counts, packs and rasterizes its own
+    band: the kernels take the band's first group as ``group_offset``.
+    The image is assembled from the bands. Returns the raster blocks'
+    image, the (alpha, blend) op sums, the band-summed compaction and
+    bitmask counters, and each band's valid entries.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels.layout import PACKED_FIELDS
+    from repro.kernels.ops import assemble_image
+
+    table = front.table
+    band = table.num_bins // bands
+
+    def body(shards, gauss_idx, entry_valid, lengths):
+        with stage_scope("exchange"):
+            def gather(name):
+                if name not in PACKED_FIELDS:
+                    return None
+                x = jax.lax.all_gather(getattr(shards, name), "model",
+                                       tiled=True)
+                return x.reshape((-1,) + x.shape[2:])
+
+            flat = Projected(**{f.name: gather(f.name)
+                                for f in dataclasses.fields(Projected)})
+        gtable = BinTable(gauss_idx=gauss_idx, entry_valid=entry_valid,
+                          lengths=lengths, overflow=jnp.zeros((), jnp.int32))
+        first = jax.lax.axis_index("model") * band
+        with stage_scope("bitmask"):
+            masks = backend.bitmasks(flat, gtable, grid, cfg.boundary_tile,
+                                     group_offset=first)
+        with stage_scope("compact"):
+            compacted = backend.compact(gtable, masks, grid,
+                                        cfg.tile_capacity)
+        with stage_scope("raster"):
+            out, counts = backend.raster_blocks(
+                flat, gtable, masks, grid, early_exit=cfg.early_exit,
+                tile_capacity=cfg.tile_capacity, group_offset=first)
+        sums = jax.lax.psum((masks.n_bit_tests, compacted.tile_entries,
+                             compacted.overflow), "model")
+        entries = jnp.sum(entry_valid.astype(jnp.int32))[None]
+        return out, counts, sums, entries
+
+    model = P("model")
+    out, counts, sums, entries = jax.shard_map(
+        body,
+        in_specs=(model,) * 4,
+        out_specs=(model, model, P(), model),
+        check_vma=False,
+    )(front.proj.shards, table.gauss_idx, table.entry_valid, table.lengths)
+    G = grid.num_groups
+    image = assemble_image(out[:G], grid, background)
+    ops = (jnp.sum(counts[:G, :, 0]), jnp.sum(counts[:G, :, 1]))
+    return image, ops, sums, entries
 
 
 def render(
@@ -422,10 +637,18 @@ def _run_frontend(
     bin at the mode's granularity. Per-shard + stable merge when sharded."""
     grid = _grid(cam, cfg)
     level, method, num_bins, capacity = _frontend_spec(cfg, grid)
-    proj, table, (n_tests, n_pairs, n_span) = _frontend(
-        backend, scene, cam, grid, level, method, num_bins, capacity,
-        resolve_feature_gather(cfg),
-    )
+    gather = resolve_feature_gather(cfg)
+    bands = model_bands(cfg, backend, getattr(scene, "num_shards", 1))
+    if bands > 1:
+        proj, table, (n_tests, n_pairs, n_span) = _frontend_bands(
+            backend, scene, cam, grid, level, method, capacity, bands,
+            gather,
+        )
+    else:
+        proj, table, (n_tests, n_pairs, n_span) = _frontend(
+            backend, scene, cam, grid, level, method, num_bins, capacity,
+            gather,
+        )
     return FrontendResult(
         proj=proj,
         table=table,
@@ -443,6 +666,27 @@ def _run_backend(
     direct per-bin rasterization for the baselines."""
     grid = _grid(cam, cfg)
     proj, table = front.proj, front.table
+    bands = model_bands(cfg, backend, getattr(proj, "num_shards", 1))
+
+    if bands > 1:
+        image, (alpha_ops, blend_ops), (n_bit_tests, tile_entries,
+                                        tile_overflow), entries = (
+            _backend_bands(backend, front, grid, cfg, background, bands))
+        lengths = table.lengths[: grid.num_groups]
+        stats = RenderStats(
+            n_visible=front.n_visible,
+            n_candidate_tests=front.n_candidate_tests,
+            n_pairs_sort=front.n_pairs_sort,
+            sort_ops=sort_op_count(lengths),
+            n_bit_tests=n_bit_tests,
+            fifo_ops=wide_count_sum(lengths) * grid.tiles_per_group,
+            alpha_ops=alpha_ops,
+            blend_ops=blend_ops,
+            tile_entries=tile_entries,
+            overflow=table.overflow + tile_overflow,
+            span_overflow=front.span_overflow,
+        )
+        return RenderResult(image=image, stats=stats, bands=entries)
 
     if cfg.mode == "gstg":
         # 4) Bitmask generation (BGM): tile-granularity tests on group

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.camera import Camera
-from repro.core.gaussians import GaussianScene, covariance3d, SH_C0
+from repro.core.gaussians import SH_C0, SH_COEFFS, GaussianScene, covariance3d
 from repro.utils import HIGHEST
 
 # Low-pass filter added to the 2D covariance diagonal (anti-aliasing), exactly
@@ -27,6 +27,11 @@ SIGMA_CUT = 3.0
 QMAX_3SIGMA = SIGMA_CUT * SIGMA_CUT
 
 SH_C1 = 0.4886025119029199
+SH_C2 = (1.0925484305920792, -1.0925484305920792, 0.31539156525252005,
+         -1.0925484305920792, 0.5462742152960396)
+SH_C3 = (-0.5900435899266435, 2.890611442640554, -0.4570457994644658,
+         0.3731763325901154, -0.4570457994644658, 1.445305721320277,
+         -0.5900435899266435)
 
 
 @jax.tree_util.register_dataclass
@@ -193,14 +198,45 @@ def proj_valid_count(proj) -> jnp.ndarray:
 
 
 def eval_sh(sh: jnp.ndarray, dirs: jnp.ndarray) -> jnp.ndarray:
-    """Evaluate SH color (deg 0 or 1 supported; higher coeffs ignored).
+    """View-dependent colour from spherical harmonics, as 3D-GS's
+    ``eval_sh``, of the degree the coefficient count gives: 1, 4, 9 or 16
+    coefficients for degrees 0 to 3. Any other count is refused.
 
-    sh: (N, K, 3); dirs: (N, 3) unit view directions.
+    sh: (N, K, 3); dirs: (N, 3) unit view directions (x, y, z).
+
+    - degree 0: C0 sh_0;
+    - degree 1 adds C1 (-y sh_1 + z sh_2 - x sh_3);
+    - degree 2 adds C2[0] xy sh_4 + C2[1] yz sh_5 + C2[2] (2zz - xx - yy)
+      sh_6 + C2[3] xz sh_7 + C2[4] (xx - yy) sh_8;
+    - degree 3 adds C3[0] y (3xx - yy) sh_9 + C3[1] xyz sh_10 + C3[2]
+      y (4zz - xx - yy) sh_11 + C3[3] z (2zz - 3xx - 3yy) sh_12 + C3[4]
+      x (4zz - xx - yy) sh_13 + C3[5] z (xx - yy) sh_14 + C3[6]
+      x (xx - 3yy) sh_15.
+
+    The colour is offset by 0.5 and clipped to [0, 1].
     """
+    n_sh = sh.shape[1]
+    if n_sh not in SH_COEFFS:
+        raise ValueError(
+            f"spherical harmonics of degree 0-3 have {SH_COEFFS} "
+            f"coefficients; got {n_sh}")
     rgb = SH_C0 * sh[:, 0, :]
-    if sh.shape[1] >= 4:
+    if n_sh >= 4:
         x, y, z = dirs[:, 0:1], dirs[:, 1:2], dirs[:, 2:3]
         rgb = rgb + SH_C1 * (-y * sh[:, 1, :] + z * sh[:, 2, :] - x * sh[:, 3, :])
+    if n_sh >= 9:
+        xx, yy, zz = x * x, y * y, z * z
+        xy, yz, xz = x * y, y * z, x * z
+        basis = (xy, yz, 2.0 * zz - xx - yy, xz, xx - yy)
+        for k, (c, b) in enumerate(zip(SH_C2, basis), start=4):
+            rgb = rgb + c * b * sh[:, k, :]
+    if n_sh == 16:
+        basis = (y * (3.0 * xx - yy), xy * z, y * (4.0 * zz - xx - yy),
+                 z * (2.0 * zz - 3.0 * xx - 3.0 * yy),
+                 x * (4.0 * zz - xx - yy), z * (xx - yy),
+                 x * (xx - 3.0 * yy))
+        for k, (c, b) in enumerate(zip(SH_C3, basis), start=9):
+            rgb = rgb + c * b * sh[:, k, :]
     return jnp.clip(rgb + 0.5, 0.0, 1.0)
 
 

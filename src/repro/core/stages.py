@@ -234,7 +234,10 @@ class PallasBackend(Backend):
     def interpret(self) -> Optional[bool]:
         return self._interpret
 
-    def bitmasks(self, proj, gtable, grid, method):
+    def bitmasks(self, proj, gtable, grid, method, group_offset=0):
+        """Row i of ``gtable`` is group ``group_offset + i`` (an int32
+        scalar): a band of the image's groups under the group-split
+        backend (core/pipeline.py), all of them from 0 otherwise."""
         from repro.kernels.bitmask_gen import bitmask_kernel
         from repro.kernels.layout import pack_features
 
@@ -244,7 +247,8 @@ class PallasBackend(Backend):
         # the gather twice, acceptable for demos/tests).
         feat = pack_features(proj, gtable.gauss_idx, gtable.entry_valid)
         masks = bitmask_kernel(
-            feat, grid, method=method, interpret=self._interpret
+            feat, grid, method=method, interpret=self._interpret,
+            group_offset=group_offset,
         )
         # Kernel masks cover the padded K axis; crop to the table capacity.
         masks = masks[:, : gtable.capacity]
@@ -279,12 +283,13 @@ class PallasBackend(Backend):
             blend_ops=jnp.sum(counts[:, 1]),
         )
 
-    def rasterize_groups(
-        self, proj, gtable, masks, compacted, grid, *,
-        background, chunk, early_exit, tile_capacity,
-    ):
+    def raster_blocks(self, proj, gtable, masks, grid, *, early_exit,
+                      tile_capacity, group_offset=0):
+        """The fused RM's output per (group, member tile): rgb and final
+        transmittance ``(G, tpg, 4, T*T)`` and (alpha_ops, blend_ops)
+        ``(G, tpg, 2)``, for ``gtable``'s groups (a band of them from
+        ``group_offset`` on, as in ``bitmasks``)."""
         from repro.kernels.layout import pack_features
-        from repro.kernels.ops import assemble_image
         from repro.kernels.raster_tile import raster_group_fused_kernel
 
         feat = pack_features(proj, gtable.gauss_idx, gtable.entry_valid)
@@ -293,7 +298,7 @@ class PallasBackend(Backend):
         padded_masks = (
             jnp.pad(masks.masks, ((0, 0), (0, pad))) if pad else masks.masks
         )
-        out, counts = raster_group_fused_kernel(
+        return raster_group_fused_kernel(
             feat,
             padded_masks,
             grid.n_groups_x,
@@ -303,6 +308,18 @@ class PallasBackend(Backend):
             tile_capacity=tile_capacity,
             with_stats=True,
             interpret=self._interpret,
+            group_offset=group_offset,
+        )
+
+    def rasterize_groups(
+        self, proj, gtable, masks, compacted, grid, *,
+        background, chunk, early_exit, tile_capacity,
+    ):
+        from repro.kernels.ops import assemble_image
+
+        out, counts = self.raster_blocks(
+            proj, gtable, masks, grid, early_exit=early_exit,
+            tile_capacity=tile_capacity,
         )
         return TileRaster(
             image=assemble_image(out, grid, background),

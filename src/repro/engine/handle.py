@@ -72,9 +72,11 @@ from repro.core.pipeline import (
     _render_with_traced_camera,
     register_render_cache,
     resolve_feature_gather,
+    splits_backend,
     unregister_render_cache,
 )
 from repro.core.projection import projected_bytes_per_gaussian
+from repro.kernels.layout import packed_bytes_per_gaussian
 from repro.launch.mesh import make_render_mesh, render_mesh_shards
 from repro.obs import (
     emit_request_spans,
@@ -281,10 +283,9 @@ class Renderer:
             # full N, as do replicated/logical-only/'flat' commits).
             scene_mb = pytree_bytes(scene) / 2**20
             # model(s, p) = per-device MB at shard count s realized p ways:
-            # parameters at 1/p + per-camera features at N_pad(s)/fdiv.
+            # parameters at 1/p + per-camera features (_device_feature_mb).
             model = lambda s, p: (
-                scene_mb / p
-                + self._feature_mb(scene, s) / self._feature_div(cfg, s, p)
+                scene_mb / p + self._device_feature_mb(cfg, scene, s, p)
             )
             # Budget escalation only applies when the caller left BOTH the
             # layout and the mesh to us ('auto' shards, no explicit mesh —
@@ -304,12 +305,11 @@ class Renderer:
                         break
             if model(shards, phys) > budget_mb:
                 layout = f"{phys}-way sharded" if phys > 1 else "replicated"
-                fdiv = self._feature_div(cfg, shards, phys)
                 raise ValueError(
                     f"scene needs {model(shards, phys):.2f} MB/device "
                     f"{layout} ({scene_mb / phys:.2f} MB parameters + "
-                    f"{self._feature_mb(scene, shards) / fdiv:.2f} MB "
-                    f"per-camera projected features at N/{fdiv}), over the "
+                    f"{self._device_feature_mb(cfg, scene, shards, phys):.2f}"
+                    f" MB per-camera projected features), over the "
                     f"{budget_mb} MB budget — raise scene_shards or "
                     f"the device count"
                 )
@@ -361,9 +361,8 @@ class Renderer:
             else render_replicated_pspec()
         )
         self._scene_mb_per_device = pytree_bytes(scene) / phys / 2**20
-        self._feature_mb_per_device = self._feature_mb(scene, shards) / (
-            self._feature_div(cfg, shards, phys)
-        )
+        self._feature_mb_per_device = self._device_feature_mb(
+            cfg, scene, shards, phys)
         # What the commit actually RUNS ('flat' for a replicated frontend,
         # even though cfg.feature_gather may still read 'auto').
         self._feature_gather = self._resolved_gather(cfg, shards, phys)
@@ -557,16 +556,34 @@ class Renderer:
 
     # -- budget model (DESIGN.md §12) ----------------------------------------
 
+    @classmethod
+    def _device_feature_mb(cls, cfg: RenderConfig, scene, shards: int,
+                           phys: int) -> float:
+        """Per-device MB of one camera's projected features: the padded
+        scene's (``_feature_mb``) over ``_feature_div``; and where the
+        gstg backend is split by group band over a physical 'model' axis
+        (``core.pipeline.splits_backend``), every Gaussian's packed kernel
+        fields besides, which each chip gathers from the others."""
+        mb = cls._feature_mb(scene, shards) / cls._feature_div(
+            cfg, shards, phys)
+        if phys > 1 and splits_backend(cfg):
+            mb += (cls._padded_rows(scene, shards)
+                   * packed_bytes_per_gaussian() / 2**20)
+        return mb
+
     @staticmethod
-    def _feature_mb(scene, shards: int) -> float:
-        """Per-camera projected-feature MB at the PADDED gaussian count
-        (padding rows project too; they are culled, not skipped)."""
+    def _padded_rows(scene, shards: int) -> int:
+        """The Gaussian count padded to whole shards (padding rows project
+        too; they are culled, not skipped)."""
         if isinstance(scene, ShardedScene):
-            n_pad = scene.padded_size
-        else:
-            n = scene.num_gaussians
-            n_pad = -(-n // max(shards, 1)) * max(shards, 1)
-        return n_pad * projected_bytes_per_gaussian() / 2**20
+            return scene.padded_size
+        return -(-scene.num_gaussians // max(shards, 1)) * max(shards, 1)
+
+    @classmethod
+    def _feature_mb(cls, scene, shards: int) -> float:
+        """Per-camera projected-feature MB at the PADDED gaussian count."""
+        return (cls._padded_rows(scene, shards)
+                * projected_bytes_per_gaussian() / 2**20)
 
     @staticmethod
     def _resolved_gather(cfg: RenderConfig, shards: int, phys: int) -> str:

@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.layout import (
     F_CONIC_A,
@@ -78,20 +79,27 @@ def bitmask_kernel(
     grid,                       # GridSpec: group origins + in-image tiles
     method: str = "ellipse",
     interpret: bool | None = None,
+    group_offset: jnp.ndarray | int = 0,
 ) -> jnp.ndarray:
     """Returns (num_groups, K) uint32 bitmasks.
 
     Group g has its top-left pixel at ((g % n_groups_x) * group,
     (g // n_groups_x) * group), a function of the grid index; member tiles
     outside the image get no bits. The grid runs over (group, lane block).
+
+    Row i of ``feat`` is group ``group_offset + i`` (an int32 scalar,
+    possibly traced). It reaches the kernel as a prefetched scalar, so each
+    chip of a band split runs the same program on its own band of the
+    image's groups. Rows past the image's groups (the padding of the last
+    band) get no bits.
     """
     num_groups, F, K = feat.shape
-    assert F == NUM_FEATURES and num_groups == grid.num_groups
+    assert F == NUM_FEATURES
     gf, tile_px = grid.gf, grid.tile
     block = next(b for b in (512, 256, LANE) if K % b == 0)
 
-    def kernel(feat_ref, out_ref):
-        g = pl.program_id(0)
+    def kernel(off_ref, feat_ref, out_ref):
+        g = off_ref[0] + pl.program_id(0)
         gx = g % grid.n_groups_x
         gy = g // grid.n_groups_x
         fb = feat_ref[0]                     # (F, block)
@@ -128,13 +136,19 @@ def bitmask_kernel(
     interpret = resolve_interpret(interpret)
     call = pl.pallas_call(
         kernel,
-        grid=(num_groups, K // block),
-        in_specs=[pl.BlockSpec((1, F, block), lambda g, k: (g, 0, k))],
-        out_specs=pl.BlockSpec((1, 1, block), lambda g, k: (g, 0, k)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(num_groups, K // block),
+            in_specs=[pl.BlockSpec((1, F, block),
+                                   lambda g, k, off: (g, 0, k))],
+            out_specs=pl.BlockSpec((1, 1, block),
+                                   lambda g, k, off: (g, 0, k)),
+        ),
         out_shape=jax.ShapeDtypeStruct((num_groups, 1, K), jnp.int32),
         interpret=interpret,
         name="gstg_bitmask",
     )
-    out = on_every_device(call, interpret)(feat)
+    out = on_every_device(call, interpret)(
+        jnp.reshape(group_offset, (1,)).astype(jnp.int32), feat)
     return jax.lax.bitcast_convert_type(out.reshape(num_groups, K),
                                         jnp.uint32)

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core.projection import proj_take
+from repro.core.projection import PROJECTED_FIELD_WIDTHS, proj_take
 from repro.utils import round_up
 
 F_MEAN_X = 0
@@ -31,6 +31,23 @@ F_VALID = 15
 NUM_FEATURES = 16
 
 LANE = 128
+
+# (Projected field, channel) of each packed feature row F_MEAN_X ..
+# F_DEPTH; the last row, F_VALID, is the entry's valid flag.
+PACKED_ROWS = (
+    ("mean2d", 0), ("mean2d", 1), ("conic", 0), ("conic", 1), ("conic", 2),
+    ("alpha", None), ("rgb", 0), ("rgb", 1), ("rgb", 2), ("radius", None),
+    ("eigvec", 0), ("eigvec", 1), ("eigval", 0), ("eigval", 1),
+    ("depth", None),
+)
+# The Projected fields ``pack_features`` reads: what a chip of the
+# group-split backend gathers from every other chip (core/pipeline.py).
+PACKED_FIELDS = tuple(dict.fromkeys(name for name, _ in PACKED_ROWS))
+
+
+def packed_bytes_per_gaussian() -> int:
+    """Bytes of ``PACKED_FIELDS`` one Gaussian holds: 15 float32."""
+    return 4 * sum(PROJECTED_FIELD_WIDTHS[f] for f in PACKED_FIELDS)
 
 
 def pack_features(
@@ -61,24 +78,8 @@ def pack_features(
             out = out[..., ch]
         return jnp.where(v, out, 0.0).astype(jnp.float32)
 
-    feats = [
-        g("mean2d", 0),
-        g("mean2d", 1),
-        g("conic", 0),
-        g("conic", 1),
-        g("conic", 2),
-        g("alpha"),
-        g("rgb", 0),
-        g("rgb", 1),
-        g("rgb", 2),
-        g("radius"),
-        g("eigvec", 0),
-        g("eigvec", 1),
-        g("eigval", 0),
-        g("eigval", 1),
-        g("depth"),
-        v.astype(jnp.float32),
-    ]
+    feats = [g(name, ch) for name, ch in PACKED_ROWS]
+    feats.append(v.astype(jnp.float32))
     packed = jnp.stack(feats, axis=1)  # (B, F, K)
     if K_pad != K:
         packed = jnp.pad(packed, ((0, 0), (0, 0), (0, K_pad - K)))

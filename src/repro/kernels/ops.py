@@ -64,12 +64,18 @@ def on_every_device(kernel, interpret: bool):
     therefore runs through ``shard_map`` on every device. Inside a camera
     batch vmapped with ``spmd_axis_name`` (the handle's batch program names
     the mesh's data axes) each device takes its own lanes; every other
-    operand axis is replicated, so on a 'model' axis each device rasterizes
-    every group. Interpreted kernels are plain XLA and are returned
-    unchanged.
+    operand axis is replicated.
+
+    On a 'model' axis the kernels do not run replicated: the group-split
+    backend (``core/pipeline.py``, DESIGN.md §10) calls them inside its own
+    ``shard_map`` over every mesh axis, each chip on its band of the
+    image's groups (the kernels' ``group_offset``). There every axis is
+    manual already and the kernel is returned unchanged, as it is when
+    interpreted (plain XLA).
     """
     mesh = jax.sharding.get_abstract_mesh()
-    if interpret or mesh.empty or mesh.size == 1:
+    if (interpret or mesh.empty or mesh.size == 1
+            or mesh.are_all_axes_manual):
         return kernel
     from jax.sharding import PartitionSpec
 

@@ -293,11 +293,15 @@ def raster_group_fused_kernel(
     early_exit: bool = True,
     tile_capacity: int | None = None,
     with_stats: bool = False,
+    group_offset: jnp.ndarray | int = 0,
 ):
     """Fused GS-TG RM. Returns (num_groups, gf*gf, 4, tile_px^2). Member
     tile s of group g has its top-left pixel at the group origin
     ((g % n_groups_x) * G, (g // n_groups_x) * G), G = gf * T, plus
     ((s % gf) * T, (s // gf) * T).
+
+    Row i of the operands is group ``group_offset + i`` (an int32 scalar,
+    possibly traced, passed as a prefetched scalar; see ``bitmask_kernel``).
 
     With ``with_stats=True`` also returns (num_groups, gf*gf, 2) int32
     (alpha_ops, blend_ops) per member tile.
@@ -311,8 +315,8 @@ def raster_group_fused_kernel(
     group_px = gf * tile_px
     masks = jax.lax.bitcast_convert_type(masks.astype(jnp.uint32), jnp.int32)
 
-    def kernel(feat_ref, mask_ref, out_ref):
-        g = pl.program_id(0)
+    def kernel(off_ref, feat_ref, mask_ref, out_ref):
+        g = off_ref[0] + pl.program_id(0)
         slot = pl.program_id(1)
         _raster_body(
             feat_ref, out_ref,
@@ -331,18 +335,22 @@ def raster_group_fused_kernel(
     interpret = resolve_interpret(interpret)
     call = pl.pallas_call(
         kernel,
-        grid=(num_groups, tpg, P // PB),
-        in_specs=[
-            pl.BlockSpec((1, F, K), lambda g, s, p: (g, 0, 0)),
-            pl.BlockSpec((1, 1, K), lambda g, s, p: (g, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, OUT_ROWS, PB), lambda g, s, p: (g, s, 0, p)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(num_groups, tpg, P // PB),
+            in_specs=[
+                pl.BlockSpec((1, F, K), lambda g, s, p, off: (g, 0, 0)),
+                pl.BlockSpec((1, 1, K), lambda g, s, p, off: (g, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, OUT_ROWS, PB), lambda g, s, p, off: (g, s, 0, p)),
+        ),
         out_shape=jax.ShapeDtypeStruct(
             (num_groups, tpg, OUT_ROWS, P), jnp.float32),
         interpret=interpret,
         name="gstg_raster_group",
     )
     out = on_every_device(call, interpret)(
+        jnp.reshape(group_offset, (1,)).astype(jnp.int32),
         feat, masks.reshape(num_groups, 1, K))
     return _split(out, with_stats)

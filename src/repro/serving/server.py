@@ -317,14 +317,18 @@ class RenderServer:
                          args={"batch_size": len(reqs), "padded": shape,
                                "signature": repr(bucket.signature)}):
             with tracer.span("serve/launch", category="serving"):
-                image = handle.render_batch(batch, pad_to=shape).image
+                out = handle.render_batch(batch, pad_to=shape)
+                image, bands = out.image, out.bands
             with tracer.span("serve/device_wait", category="serving"):
                 image.block_until_ready()
             t_done = self._clock()
             with tracer.span("serve/fetch", category="serving"):
                 images = np.asarray(image)
+                if bands is not None:
+                    bands = np.asarray(bands)
         self._complete(bucket, images, mark, t_done, padded=shape,
-                       span_args={"scene_id": reqs[0].scene_id})
+                       span_args={"scene_id": reqs[0].scene_id},
+                       bands=bands)
 
     def _dispatch_stream(self, bucket: Bucket) -> None:
         """Dispatch a stream bucket: frames run IN ORDER through the
@@ -365,10 +369,11 @@ class RenderServer:
                 reg.histogram("engine.compile_s").sum, self._clock())
 
     def _complete(self, bucket: Bucket, images, mark: tuple, t_done: float,
-                  *, padded: int, span_args: dict) -> None:
+                  *, padded: int, span_args: dict, bands=None) -> None:
         """Fold a finished dispatch into the stats, results and request
         stamps: launched at ``mark``, device work done at ``t_done``, images
-        on the host now."""
+        on the host now. ``bands``: each request's valid group-table entries
+        per band, where a group-split backend drew it (else None)."""
         reqs = bucket.requests
         before, compiles, compile_s, t0 = mark
         t1 = self._clock()
@@ -387,6 +392,7 @@ class RenderServer:
             fetch_s=t1 - t_done,
             compiles=reg.counter("engine.compiles_total").value - compiles,
             compile_s=reg.histogram("engine.compile_s").sum - compile_s,
+            band_entries=None if bands is None else bands[:len(reqs)],
         )
         tracer = get_tracer()
         for req, img, lat in zip(reqs, images, latencies):

@@ -55,6 +55,15 @@ def percentile(values: List[float], q: float) -> float:
     return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
 
 
+def band_skew(entries) -> float:
+    """The largest band's valid entries over the mean band's: 1.0 when the
+    bands hold equal work, up to the band count when one holds it all (and
+    1.0 for a frame with no entries)."""
+    entries = [float(e) for e in entries]
+    mean = sum(entries) / len(entries)
+    return max(entries) / mean if mean > 0 else 1.0
+
+
 def _latency_histogram() -> Histogram:
     return Histogram(cap=LATENCY_RESERVOIR)
 
@@ -165,6 +174,7 @@ class ServingStats:
         fetch_s: Optional[float] = None,
         compiles: Optional[int] = None,
         compile_s: Optional[float] = None,
+        band_entries=None,
     ) -> None:
         """Fold one dispatch of ``batch_size`` requests. The optional
         readings publish into the registry in dispatch order, so the last
@@ -173,7 +183,11 @@ class ServingStats:
         ``serving.fetch_s`` (image copy to the host, ``fetch_s``); per
         dispatch ``serving.batch_size``, ``serving.dispatch_compiles`` and
         ``serving.dispatch_compile_s`` (XLA compiles, or loads from the
-        persistent cache, while the dispatch ran)."""
+        persistent cache, while the dispatch ran). ``band_entries``, one row
+        of valid group-table entries per band for each request that a
+        group-split backend drew, publishes per request
+        ``serving.band_skew``: the largest band's entries over the mean
+        band's (``band_skew``)."""
         delta = None
         if cache_before is not None and cache_after is not None:
             delta = cache_delta(cache_before, cache_after)
@@ -210,6 +224,9 @@ class ServingStats:
             reg.histogram("serving.dispatch_compiles").observe(compiles)
         if compile_s is not None:
             reg.histogram("serving.dispatch_compile_s").observe(compile_s)
+        if band_entries is not None:
+            reg.histogram("serving.band_skew").observe_many(
+                [band_skew(row) for row in band_entries])
 
     # -- aggregate views ----------------------------------------------------
 

@@ -99,9 +99,9 @@ def test_grid_spec_validation():
 
 
 def _bin_oracle(bin_id, depth, gauss_idx, num_bins, capacity):
-    """BinTable fields from np.lexsort over (bin id, depth, pair position):
+    """BinTable fields from np.lexsort over (bin id, depth, Gaussian index):
     the lexicographic order the binning sort must reproduce exactly."""
-    order = np.lexsort((np.arange(bin_id.size), depth, bin_id))
+    order = np.lexsort((gauss_idx, depth, bin_id))
     sorted_bins, sorted_gauss = bin_id[order], gauss_idx[order]
     lengths = np.bincount(sorted_bins, minlength=num_bins + 1)[:num_bins]
     starts = np.concatenate([[0], np.cumsum(lengths)[:-1]])
@@ -118,8 +118,9 @@ def _bin_oracle(bin_id, depth, gauss_idx, num_bins, capacity):
 
 def _oracle_pairs(rng, n_pairs, num_bins, depth_pool, invalid_share):
     """Pair arrays with depths drawn from ``depth_pool`` (heavy ties) and a
-    shuffled Gaussian-index payload, so a tie broken by anything but pair
-    position shows in gauss_idx. Invalid slots carry bin num_bins and +inf."""
+    shuffled Gaussian index, so a tie broken by anything but the Gaussian
+    index (pair position, say) shows in gauss_idx. Invalid slots carry bin
+    num_bins and +inf."""
     hit = rng.random(n_pairs) >= invalid_share
     bin_id = np.where(hit, rng.integers(0, num_bins, n_pairs), num_bins)
     depth = np.where(hit, rng.choice(np.asarray(depth_pool, np.float32),
@@ -141,8 +142,8 @@ def _oracle_pairs(rng, n_pairs, num_bins, depth_pool, invalid_share):
 )
 def test_bin_pairs_matches_lexsort_oracle(n_pairs, num_bins, capacity,
                                           depth_pool, invalid_share, lanes):
-    """bin_pairs == a NumPy lexsort over (bin id, depth, pair position),
-    field for field: depth ties and -0.0/0.0 break by pair position, +inf
+    """bin_pairs == a NumPy lexsort over (bin id, depth, Gaussian index),
+    field for field: depth ties and -0.0/0.0 break by Gaussian index, +inf
     invalid slots never enter a bin, and lengths and overflow count a bin's
     entries past its capacity."""
     rng = np.random.default_rng(n_pairs + num_bins)

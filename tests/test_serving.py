@@ -194,6 +194,29 @@ def test_stats_cache_delta():
     assert cache_delta(before, after) == {"hits": 3, "misses": 1}
 
 
+@pytest.mark.parametrize("entries,skew", [
+    ([10, 10, 10, 10], 1.0),          # an even split
+    ([40, 0, 0, 0], 4.0),             # every entry on one chip
+    ([30, 10, 20, 20], 1.5),
+    ([0, 0, 0, 0], 1.0),              # a frame with no entries
+])
+def test_stats_publish_band_skew_per_request(entries, skew):
+    """A dispatch a group-split backend drew publishes, per request, its
+    largest band's entries over the mean band's; one that carries no band
+    entries publishes nothing."""
+    from repro.obs import MetricsRegistry
+    from repro.serving.stats import band_skew
+
+    assert band_skew(entries) == skew
+    reg = MetricsRegistry()
+    stats = ServingStats(registry=reg)
+    stats.record_dispatch(("sig",), 1, 1, 0.01, [0.01])
+    assert reg.get("serving.band_skew") is None
+    stats.record_dispatch(("sig",), 2, 2, 0.01, [0.01, 0.02],
+                          band_entries=[entries, [5, 5, 5, 5]])
+    assert reg.get("serving.band_skew").values() == [skew, 1.0]
+
+
 def test_stats_latency_memory_is_bounded():
     """A long-lived server must not grow one float per request: latencies
     live in reservoir histograms (DESIGN.md §14) — bounded storage, exact

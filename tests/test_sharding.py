@@ -313,6 +313,68 @@ def test_feature_budget_model_scales_inverse_d(tiny_scene):
     assert Renderer._feature_div(RenderConfig(), 1, 1) == 1
 
 
+@pytest.mark.parametrize("degree,floats", [(0, 14), (1, 23), (2, 38),
+                                           (3, 59)])
+def test_budget_model_counts_the_scene_from_its_sh_coefficients(degree,
+                                                                 floats):
+    """The handle's parameter term is the committed scene's bytes: 3 + 3 +
+    4 + 1 + 3 (d + 1)^2 floats a Gaussian, 59 at SH degree 3, for the
+    generator's (d + 1)^2 coefficients, replicated and in the padded
+    sharded layout."""
+    import jax
+
+    from repro import engine
+    from repro.core import random_scene
+    from repro.core.pipeline import RenderConfig
+    from repro.sharding.scene import shard_scene
+    from repro.utils import pytree_bytes
+
+    scene = random_scene(jax.random.key(degree), 301, sh_degree=degree)
+    assert scene.sh.shape == (301, (degree + 1) ** 2, 3)
+    assert pytree_bytes(scene) == 301 * 4 * floats
+    assert pytree_bytes(shard_scene(scene, 4)) == 304 * 4 * floats  # 4 x 76
+    r = engine.open(scene, RenderConfig(), device_budget_mb=1024.0)
+    try:
+        assert r.stats()["scene_mb_per_device"] == 301 * 4 * floats / 2**20
+    finally:
+        r.close()
+
+
+def test_budget_model_refuses_sh_degrees_past_3():
+    import jax
+
+    from repro.core import random_scene
+
+    with pytest.raises(ValueError, match="sh_degree"):
+        random_scene(jax.random.key(0), 10, sh_degree=4)
+
+
+def test_budget_model_counts_the_band_split_feature_gather(tiny_scene):
+    """Under the group-split backend (gstg on Pallas over a physical
+    'model' axis) each chip holds its shard's projected features and every
+    Gaussian's 15 packed kernel fields; other backends hold no such copy."""
+    from repro.core.pipeline import RenderConfig
+    from repro.core.projection import projected_bytes_per_gaussian
+    from repro.engine import Renderer
+    from repro.kernels.layout import packed_bytes_per_gaussian
+
+    assert packed_bytes_per_gaussian() == 60
+    n_pad = -(-tiny_scene.num_gaussians // 4) * 4
+    split = RenderConfig(backend="pallas", scene_shards=4)
+    got = Renderer._device_feature_mb(split, tiny_scene, 4, 4)
+    want = n_pad * (projected_bytes_per_gaussian() / 4 + 60) / 2**20
+    assert abs(got - want) < 1e-12
+    # Logical shards, the reference backend and 'flat' split nothing.
+    per_shard = n_pad * projected_bytes_per_gaussian() / 2**20
+    assert Renderer._device_feature_mb(split, tiny_scene, 4, 1) == per_shard
+    reference = RenderConfig(backend="reference", scene_shards=4)
+    assert Renderer._device_feature_mb(
+        reference, tiny_scene, 4, 4) == per_shard / 4
+    flat = RenderConfig(backend="pallas", scene_shards=4,
+                        feature_gather="flat")
+    assert Renderer._device_feature_mb(flat, tiny_scene, 4, 4) == per_shard
+
+
 @pytest.mark.parametrize("bg,bt", [("aabb", "aabb"), ("obb", "ellipse")])
 def test_scene_sharded_lossless_combos(tiny_scene, jit_render_fn, bg, bt):
     """Sharding composes with the §7 losslessness combos: gstg sharded ==
@@ -510,3 +572,124 @@ def test_scene_sharded_virtual_devices(devices, shards):
     assert out.returncode == 0, out.stderr[-2000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["failures"] == [], res
+
+
+# ---------------------------------------------------------------------------
+# Group-split backend on a physical 'model' axis (DESIGN.md §10)
+# ---------------------------------------------------------------------------
+
+_BAND_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json, re
+import jax, jax.numpy as jnp, numpy as np
+
+from repro import engine
+from repro.core import orbit_cameras, pipeline, random_scene
+from repro.core.gaussians import GaussianScene
+from repro.core.pipeline import RenderConfig, stage_of
+from repro.launch.mesh import make_render_mesh
+from repro.obs import get_registry
+from repro.serving.queue import RenderRequest
+from repro.serving.server import RenderServer
+from repro.serving.stats import band_skew
+from repro.sharding.scene import PAD_OPACITY, ShardedScene, shard_scene
+
+case = %(case)r
+# Project each chip's shard in chunks, as paper-size shards are.
+pipeline.PROJECT_ROWS = 32
+if case == "sh3":
+    scene = random_scene(jax.random.key(3), 300, extent=3.0, sh_degree=3)
+elif case == "depth_ties":
+    # Gaussians i, i + 60, i + 120 and i + 180 (one in each shard) share
+    # everything but their colour, so every bin they cover holds four
+    # entries of one depth from four shards, in global index order.
+    base = random_scene(jax.random.key(4), 240, extent=3.0, sh_degree=3)
+    same = lambda x: jnp.tile(x[:60], (4,) + (1,) * (x.ndim - 1))
+    scene = GaussianScene(
+        means3d=same(base.means3d), log_scales=same(base.log_scales),
+        quats=same(base.quats), opacity=same(base.opacity), sh=base.sh)
+else:
+    # Three shards of Gaussians and a fourth of padding rows alone.
+    real = shard_scene(
+        random_scene(jax.random.key(5), 225, extent=3.0, sh_degree=3), 3)
+    pad = jax.tree.map(lambda x: jnp.zeros((1,) + x.shape[1:], x.dtype),
+                       real.shards)
+    pad.opacity = jnp.full_like(pad.opacity, PAD_OPACITY)
+    scene = ShardedScene(
+        shards=jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                            real.shards, pad), num_real=225)
+
+cams = orbit_cameras(2, 4.5, 160, 96)   # 3 x 2 = 6 groups, bands of 2
+cfg = RenderConfig(mode="gstg", backend="pallas", group_capacity=256,
+                   tile_capacity=256, span=6, scene_shards=4)
+mesh = make_render_mesh(4, scene_shards=4)
+split_bands = pipeline.model_bands
+
+
+def served(split):
+    pipeline.model_bands = split_bands if split else (lambda *a: 1)
+    with engine.open(scene, cfg, mesh=mesh) as h:
+        out = h.render_batch(cams, pad_to=2)
+        text = h.program_text(cams, pad_to=2)
+    return jax.tree.map(np.asarray, out), text
+
+
+split, text = served(True)
+replicated, _ = served(False)
+pipeline.model_bands = split_bands
+failures = []
+if not (split.image == replicated.image).all():
+    failures.append(("image", float(np.abs(split.image
+                                           - replicated.image).max())))
+for name in vars(replicated.stats):
+    a, b = getattr(split.stats, name), getattr(replicated.stats, name)
+    if not (a == b).all():
+        failures.append((name, a.tolist(), b.tolist()))
+if split.stats.overflow.any() or split.stats.span_overflow.any():
+    failures.append(("lossless", split.stats.overflow.tolist()))
+if replicated.bands is not None or split.bands.shape != (2, 4):
+    failures.append(("bands", None if split.bands is None
+                     else split.bands.shape))
+elif not (split.bands.sum(axis=1) * 16 == split.stats.n_bit_tests).all():
+    failures.append(("band entries", split.bands.tolist()))
+stages = {stage_of(n) for n in re.findall(r'op_name="([^"]*)"', text)}
+if "exchange" not in stages or "all-to-all" not in text:
+    failures.append(("exchange", sorted(s for s in stages if s)))
+
+# The server publishes each request's band skew.
+with RenderServer({"s": scene}, mesh=mesh, scene_shards=4,
+                  max_batch=2, max_wait=0.0) as server:
+    for i, cam in enumerate(cams):
+        server.submit(RenderRequest(i, "s", cam, cfg))
+    server.drain()
+skews = get_registry().get("serving.band_skew").values()
+want = [band_skew(row) for row in split.bands]
+if not np.allclose(skews, want) or min(skews) < 1.0:
+    failures.append(("band_skew", skews, want))
+print(json.dumps({"failures": failures,
+                  "bands": split.bands.tolist()}))
+"""
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("case", ["sh3", "depth_ties", "padding_shard"])
+def test_group_split_backend_is_bitwise_on_four_devices(case):
+    """Gaussian-sharded over four virtual devices (a (data=1, model=4)
+    mesh), the backend split by group band equals the backend replicated on
+    every device bitwise: image and every counter, at SH degree 3, with 6
+    groups in bands of 2 (the last band padded), depth ties across shards,
+    and a shard of padding alone. The program exchanges tables under
+    ``gstg/exchange``, returns each band's entries, and the server
+    publishes their skew."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _BAND_SCRIPT % {"case": case}],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["failures"] == [], res
+    if case == "padding_shard":
+        assert all(row[3] >= 0 for row in res["bands"])

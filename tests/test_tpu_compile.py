@@ -139,14 +139,53 @@ def test_data_parallel_batch_runs_kernels_on_own_lanes(v5e_2x2, monkeypatch):
         assert shape.split("[", 1)[1].startswith("6,"), shape
 
 
+def test_model_axis_splits_kernels_by_group_band(v5e_2x2, monkeypatch):
+    """A scene Gaussian-sharded over a (data=1, model=4) mesh: the bin
+    tables cross the chips in one all-to-all and each chip's kernels take
+    a quarter of the groups, 2 of 8, not all 8."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.gaussians import random_scene
+    from repro.core.pipeline import RenderConfig, _render_with_traced_camera
+    from repro.engine.handle import _vmap_cameras
+    from repro.sharding.scene import shard_scene
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    mesh = Mesh(np.array(v5e_2x2).reshape(1, 4), ("data", "model"))
+    cfg = RenderConfig(mode="gstg", backend="pallas", group_capacity=128,
+                       tile_capacity=128, span=4, scene_shards=4)
+    one = _render_with_traced_camera(cfg, 256, 128, 0.01, 100.0)  # 8 groups
+    model = NamedSharding(mesh, P("model"))
+    lanes = NamedSharding(mesh, P("data"))
+
+    def spec(shape, sharding):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    scene = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=model),
+        shard_scene(random_scene(jax.random.key(0), 500, sh_degree=3), 4))
+    args = ([scene, spec((1, 3, 3), lanes), spec((1, 3), lanes)]
+            + [spec((1,), lanes)] * 4 + [spec((3,), NamedSharding(mesh, P()))])
+    with jax.set_mesh(mesh):
+        text = jax.jit(_vmap_cameras(one, mesh)).lower(*args).compile(
+        ).as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2                     # bitmask + fused raster
+    assert "all-to-all" in text
+    for line in kernels:
+        shape = line.split("= ", 1)[1].split("{", 1)[0]
+        assert shape.split("[", 1)[1].startswith("2,"), shape
+
+
 def test_stage_scopes_change_only_metadata_on_the_chip(one_chip, monkeypatch,
                                                        strip_metadata):
     """Compiled for the chip, the served batch program is the same with its
     stage scopes as without them, once metadata and what the kernels' names
     set are left out; each kernel runs in its own stage's scope under its
-    own name, and the program's one sort is ``bin``'s: keys (bin id, depth)
-    with the Gaussian index carried as a third operand, so no permutation
-    is built to gather through."""
+    own name, and the program's one sort is ``bin``'s: keys (bin id, depth,
+    Gaussian index), so no permutation is built to gather through."""
     import contextlib
     import re
 
@@ -196,6 +235,6 @@ def test_stage_scopes_change_only_metadata_on_the_chip(one_chip, monkeypatch,
                        "gstg_raster_group": "raster"}
     sorts = [stage for name, stage in ops.items() if name.startswith("sort")]
     assert sorts == ["bin"]
-    # The program's three operands (bin id, depth, Gaussian index), then
-    # the iota the chip's compiler appends to make the sort stable.
-    assert sort_operands == [(["s32", "f32", "s32", "s32"], ["iota"])]
+    # The program's three keys (bin id, depth, Gaussian index) and no
+    # more: the sort is unstable, so the chip's compiler appends no iota.
+    assert sort_operands == [(["s32", "f32", "s32"], [])]
